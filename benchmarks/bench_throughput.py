@@ -106,7 +106,7 @@ def _fused_fps(cfg: SimConfig, num_envs: int, frames: int,
         return state
 
     if mesh is not None:
-        from repro.compat import P, shard_map
+        from jax import P, shard_map
         chunk_exec = shard_map(
             chunk_body, mesh=mesh,
             in_specs=(jax_env.world_specs(axis), jax_env.state_specs(axis),

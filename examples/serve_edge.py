@@ -36,4 +36,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import use_compile_cache
+    use_compile_cache()
     main()

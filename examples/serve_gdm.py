@@ -88,4 +88,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import use_compile_cache
+    use_compile_cache()
     main()

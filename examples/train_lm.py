@@ -32,4 +32,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -395,10 +395,9 @@ class LearnGDMController:
             # carry/replay/update replicated; world, reset draws (E, ...)
             # and frame draws (T, E, ...) sharded on the env dim, except the
             # replay-sample uniforms every shard must consume identically.
-            # check_vma=False: the replicated agent/replay carry through
-            # lax.scan+cond trips the conservative replication checker on
-            # older jax; the specs themselves guarantee replication here.
-            from repro.compat import P, shard_map
+            # check_vma=False: the specs themselves guarantee that the
+            # agent/replay carry stays replicated through lax.scan+cond.
+            from jax import P, shard_map
             from repro.distributed.sharding import draw_specs
             frame_draw_keys = ("explore", "q_rand", "arrival", "waypoint",
                                "sample", "mac_attempt", "mac_channel")

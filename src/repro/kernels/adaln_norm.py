@@ -12,9 +12,12 @@ variant additionally folds the previous sublayer's gated residual add into
 the same tile pass and emits BOTH the modulated output and the new residual
 stream (two outputs), so the residual never makes a separate trip.
 
-Shapes: x/residual (B, S, d); shift/scale/gate (B, d) — one modulation
+Shapes: x/residual (B, S, d); shift/scale/gate (B, 1, d) — one modulation
 vector per batch row (the DiT conditions on timestep + prompt, not on
-position); weight/bias (d,) — the LayerNorm affine params.
+position); weight/bias (d,) — the LayerNorm affine params.  The modulation
+block is (1, 1, d): on the TPU the last two block dims must be (8, 128)
+multiples or equal to the array's, and a (1, d) block over a (B, d) array
+fails to lower for every B > 1.
 """
 from __future__ import annotations
 
@@ -51,7 +54,7 @@ def _adaln_epilogue_kernel(h_ref, g_ref, r_ref, w_ref, b_ref, sc_ref, sh_ref,
 def adaln_norm_pallas(x, shift, scale, weight, bias, gate=None, residual=None,
                       *, eps: float = 1e-5, block_rows: int = 128,
                       interpret: bool = False):
-    """x: (B, S, d); shift/scale/gate: (B, d); weight/bias: (d,).
+    """x: (B, S, d); shift/scale/gate: (B, 1, d); weight/bias: (d,).
 
     Without ``gate``/``residual``: returns LN(x) * (1 + scale) + shift.
     With both: computes r = residual + gate * x first and returns
@@ -68,7 +71,7 @@ def adaln_norm_pallas(x, shift, scale, weight, bias, gate=None, residual=None,
 
     grid = (b, s_p // br)
     row_spec = pl.BlockSpec((1, br, d), lambda bb, rr: (bb, rr, 0))
-    vec_spec = pl.BlockSpec((1, d), lambda bb, rr: (bb, 0))
+    vec_spec = pl.BlockSpec((1, 1, d), lambda bb, rr: (bb, 0, 0))
     prm_spec = pl.BlockSpec((1, d), lambda bb, rr: (0, 0))
     w2, b2 = weight.reshape(1, d), bias.reshape(1, d)
 
@@ -80,6 +83,7 @@ def adaln_norm_pallas(x, shift, scale, weight, bias, gate=None, residual=None,
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((b, s_p, d), x.dtype),
             interpret=interpret,
+            name="adaln_norm",
         )(x, w2, b2, scale, shift)
         return out[:, :s]
 
@@ -92,6 +96,7 @@ def adaln_norm_pallas(x, shift, scale, weight, bias, gate=None, residual=None,
         out_shape=[jax.ShapeDtypeStruct((b, s_p, d), x.dtype),
                    jax.ShapeDtypeStruct((b, s_p, d), x.dtype)],
         interpret=interpret,
+        name="adaln_norm_epilogue",
     )(x, gate, residual, w2, b2, scale, shift)
     return y[:, :s], res[:, :s]
 

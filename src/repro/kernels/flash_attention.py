@@ -113,6 +113,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     out = out[:, :, :sq]
     return jnp.moveaxis(out, 1, 2)

@@ -91,10 +91,12 @@ def adaln_norm(x, shift, scale, weight, bias, gate=None, residual=None, *,
     fused in first and ``(y, new_residual)`` is returned.
     """
     b, _, d = x.shape
-    shift, scale = shift.reshape(b, d), scale.reshape(b, d)
-    if gate is not None:
-        gate = gate.reshape(b, d)
     mode = _resolve(impl)
+    # the oracle takes (B, d); the kernel reads (1, 1, d) blocks of (B, 1, d)
+    vec = (b, d) if mode == "xla" else (b, 1, d)
+    shift, scale = shift.reshape(vec), scale.reshape(vec)
+    if gate is not None:
+        gate = gate.reshape(vec)
     if mode == "xla":
         return ref.adaln_norm(x, shift, scale, weight, bias, gate=gate,
                               residual=residual, eps=eps)

@@ -9,8 +9,9 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax import make_mesh
+from jax.sharding import AxisType
 
-from repro.compat import AxisType, make_mesh  # noqa: F401  (compat policy)
 from repro.configs.base import MULTI_POD, SINGLE_POD, MeshConfig
 
 

@@ -51,6 +51,41 @@ def default_gdm_impl(impl: Optional[str], cfg: ModelConfig) -> str:
     return getattr(cfg, "gdm_impl", "auto") or "auto"
 
 
+def block_runner(cfg: ModelConfig, schedule, *, steps_per_block: int,
+                 total_steps: int, impl: str, mesh=None,
+                 batch_axis: str = "batch"):
+    """The jitted stacked-block call of a service:
+    ``(params, latent, prompt, block_idx) -> (latent, x0)``.
+
+    The params are an argument: jit embeds closed-over arrays in the
+    program as constants, one copy per compiled bucket.  With ``mesh`` each
+    device runs the call on its own rows of the batch (the DiT is
+    per-sample independent) under ``shard_map``: GSPMD cannot partition
+    Pallas kernels, and their out_shapes carry no vma for the checker.
+    """
+    def run(params, latent, prompt, block_idx):
+        with jax.named_scope("gdm_block"):
+            return run_block_batched(params, latent, prompt, cfg, schedule,
+                                     block_idx,
+                                     steps_per_block=steps_per_block,
+                                     total_steps=total_steps, impl=impl)
+
+    jit_kw = {}
+    if jax.default_backend() in ("gpu", "tpu"):
+        # donate the stacked latent: the block call overwrites it anyway
+        # (no-op on CPU, where donation only warns)
+        jit_kw["donate_argnums"] = (1,)
+    if mesh is not None:
+        from repro.distributed.sharding import batch_shardings
+        row = jax.P(batch_axis)
+        run = jax.shard_map(run, mesh=mesh, in_specs=(jax.P(), row, row, row),
+                            out_specs=(row, row), check_vma=False)
+        data, replicated = batch_shardings(mesh, batch_axis)
+        jit_kw["in_shardings"] = (replicated, data, data, data)
+        jit_kw["out_shardings"] = (data, data)
+    return jax.jit(run, **jit_kw)
+
+
 class GDMService:
     """One GDM denoising-chain service (real reduced DiT) for the engine."""
 
@@ -72,16 +107,15 @@ class GDMService:
         # one mesh shards the stacked batch dim across devices (the DiT is
         # per-sample independent: pure data parallelism, zero communication)
         self.mesh = mesh
-        self._batch_axis = batch_axis
         self._ndev = 1 if mesh is None else mesh.shape[batch_axis]
         # persistent per-bucket host staging buffers (see run_batch)
         self._buffers: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]] \
             = {}
         self._slot_batch: Optional["SlotBatch"] = None
-        # compiled block-call cache keyed by impl (benches flip impls on one
-        # service without recompiling the default hot path)
-        self._runners: Dict[str, object] = {}
-        self._runner = self._runner_for(self.impl)
+        self._runner = block_runner(self.cfg, self.schedule,
+                                    steps_per_block=steps_per_block,
+                                    total_steps=total, impl=self.impl,
+                                    mesh=mesh, batch_axis=batch_axis)
         # observability (repro.serving.tracing): instrument() attaches a
         # MetricsRegistry; _call_runner then wall-clocks every compiled call
         # and flags compile events by first-seen (impl, bucket) shape key
@@ -101,32 +135,12 @@ class GDMService:
                                          impl=self.impl))
         self.omega = np.zeros(num_blocks + 1)
         self.omega[1:] = np.maximum.accumulate(np.clip(q, 0.0, 1.0))
-
-    def _runner_for(self, impl: str):
-        """The jitted stacked-block call for ``impl`` (cached per impl)."""
-        runner = self._runners.get(impl)
-        if runner is not None:
-            return runner
-        cfg, params, schedule = self.cfg, self.params, self.schedule
-        spb, total = self.steps_per_block, self.num_blocks * self.steps_per_block
-
-        def _run(latent, prompt, block_idx):
-            return run_block_batched(params, latent, prompt, cfg, schedule,
-                                     block_idx, steps_per_block=spb,
-                                     total_steps=total, impl=impl)
-
-        jit_kw = {}
-        if jax.default_backend() in ("gpu", "tpu"):
-            # donate the stacked latent: the block call overwrites it anyway
-            # (no-op on CPU, where donation only warns)
-            jit_kw["donate_argnums"] = (0,)
-        if self.mesh is not None:
+        if mesh is not None:
+            # replicate the weights once, after Ω (measured unsharded), so
+            # the sharded block calls never re-transfer them
             from repro.distributed.sharding import batch_shardings
-            data, _ = batch_shardings(self.mesh, self._batch_axis)
-            jit_kw["in_shardings"] = (data, data, data)
-            jit_kw["out_shardings"] = (data, data)
-        runner = self._runners[impl] = jax.jit(_run, **jit_kw)
-        return runner
+            _, replicated = batch_shardings(mesh, batch_axis)
+            self.params = jax.device_put(self.params, replicated)
 
     def instrument(self, metrics, sample_every: int = 16) -> None:
         """Attach a :class:`repro.serving.tracing.MetricsRegistry`: jitted
@@ -148,7 +162,7 @@ class GDMService:
         """The one seam both batch paths (run_batch / SlotBatch.step) issue
         their device call through; uninstrumented it IS the raw call."""
         if self.metrics is None:
-            return self._runner(latent_buf, prompt_buf, idx_buf)
+            return self._runner(self.params, latent_buf, prompt_buf, idx_buf)
         m = self.metrics
         key = (self.impl, int(latent_buf.shape[0]))
         first = key not in self._compiled_keys
@@ -157,9 +171,10 @@ class GDMService:
         if not first:
             self._steady_calls += 1
             if self._steady_calls % self._sample_every:
-                return self._runner(latent_buf, prompt_buf, idx_buf)
+                return self._runner(self.params, latent_buf, prompt_buf,
+                                    idx_buf)
         t0 = time.perf_counter()
-        out = self._runner(latent_buf, prompt_buf, idx_buf)
+        out = self._runner(self.params, latent_buf, prompt_buf, idx_buf)
         jax.block_until_ready(out)
         dt_ms = (time.perf_counter() - t0) * 1e3
         if first:

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import P, shard_map
+from jax import P, shard_map
 from repro.sim.env import IDLE, PENDING, SimConfig
 
 
@@ -604,9 +604,8 @@ def build_eval_round(cfg: SimConfig, act_fn, *,
 
     # in_specs pytree prefixes: params replicated (every shard runs the same
     # policy), world/state per-field, the draws dict uniformly (T, E, ...).
-    # check_vma=False: the replicated frame/key carry through lax.scan trips
-    # the conservative replication checker on older jax; the specs above are
-    # what guarantee replication here.
+    # check_vma=False: the specs above are what guarantee that the frame/key
+    # carry stays replicated through lax.scan.
     sharded = shard_map(
         round_fn, mesh=mesh,
         in_specs=(P(), world_specs(axis), state_specs(axis),

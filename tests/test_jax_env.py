@@ -5,7 +5,7 @@ The harness drives both engines from the *same* imported state
 (``state_from_numpy``) with the *same* per-frame draws (arrivals, waypoint
 redraws, exploration placements) and asserts matching integer state
 (poa / blocks_done / chain / collisions ...) and float-tolerance rewards.
-It runs under ``jax.experimental.enable_x64`` so both engines compute the
+It runs under ``jax.enable_x64(True)`` so both engines compute the
 RWP kinematics and priorities in float64 — trajectories then agree exactly,
 not just statistically.
 """
@@ -16,7 +16,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import LearnGDMController, vec_greedy_mac
 from repro.sim import EdgeSimulator, SimConfig, VecEdgeSimulator, jax_env
@@ -76,7 +75,7 @@ def drive_pair(world_seed, ep_seeds, frames, *, placement_fn, rng):
 
 @pytest.mark.parametrize("world_seed,ep0", [(0, 101), (7, 900)])
 def test_jax_engine_matches_numpy_random_placements(world_seed, ep0):
-    with enable_x64():
+    with jax.enable_x64(True):
         cfg = SimConfig(**TABLE2, seed=world_seed)
         rng = np.random.default_rng(42 + world_seed)
         drive_pair(world_seed, [ep0 + i for i in range(3)], cfg.horizon,
@@ -88,7 +87,7 @@ def test_jax_engine_matches_numpy_random_placements(world_seed, ep0):
 def test_jax_engine_matches_numpy_hotspot_placements():
     """Concentrated load (only BS 0..2) forces C3 capacity blocking — the
     rank/tie-break-sensitive path must still agree."""
-    with enable_x64():
+    with jax.enable_x64(True):
         cfg = SimConfig(**TABLE2, seed=3)
         rng = np.random.default_rng(5)
         drive_pair(3, [55, 56], cfg.horizon,

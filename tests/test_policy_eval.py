@@ -20,8 +20,8 @@ training + batched eval path.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (GreedyController, GreedyPoAPolicy,
                         LearnGDMController, LearnedPolicy, RandomPolicy,
@@ -82,7 +82,7 @@ def _policies(cfg):
 
 
 def test_eval_fused_matches_batched_rollout_under_injected_draws():
-    with enable_x64():
+    with jax.enable_x64(True):
         cfg = SimConfig(num_ues=8, num_channels=2, horizon=16, seed=3)
         e, u, t = 3, cfg.num_ues, cfg.horizon
         rng = np.random.default_rng(5)
