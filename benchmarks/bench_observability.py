@@ -85,7 +85,6 @@ def _serve_once(cfg, services, fleet, *, tracing, scheduling):
         for svc in services.values():
             svc.metrics = None
             svc._compiled_keys = set()
-            svc._steady_calls = 0
     return stats, wall, tracer
 
 

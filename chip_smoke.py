@@ -202,8 +202,8 @@ def one_chip(model_cfg, want_impl: str = "pallas") -> None:
         f"mean reward {np.mean(hist['reward']):.4f}")
 
     metrics = MetricsRegistry()
-    for svc in services.values():
-        svc.instrument(metrics, sample_every=1)
+    for sid, svc in services.items():
+        svc.instrument(metrics, sid)
     factory = lambda c: LearnedPolicy(ctrl.agent, "learn-gdm")  # noqa: E731
     clusters = {}
     for mode in ("quantum", "continuous"):
@@ -223,11 +223,12 @@ def one_chip(model_cfg, want_impl: str = "pallas") -> None:
             raise RuntimeError(f"no chain ran past one block under {mode}")
         clusters[mode] = cluster
     compile_h = metrics.histogram("gdm_compile_ms")
-    steady_h = metrics.histogram("gdm_run_batch_ms")
+    launch_h = metrics.histogram("launch_ms")
+    wait_h = metrics.histogram("device_wait_ms")
     log(f"block calls: {compile_h.count} first-at-bucket (compile) "
-        f"{compile_h.total / 1e3:.3f} s, {steady_h.count} steady "
-        f"{steady_h.total / 1e3:.3f} s (p50 {steady_h.percentile(50):.3f} "
-        f"ms)")
+        f"{compile_h.total / 1e3:.3f} s; {launch_h.count} launches "
+        f"{launch_h.total / 1e3:.3f} s, device wait {wait_h.total / 1e3:.3f}"
+        f" s (p50 {wait_h.percentile(50):.3f} ms)")
 
     svc = services[0]
     states = live_states(clusters["continuous"], 0)

@@ -151,10 +151,10 @@ def serve_policy(cfg: SimConfig, policy, frames: int, *,
         from repro.serving.tracing import Tracer
         tracer = Tracer()
     if tracer is not None:
-        for svc in services.values():
+        for sid, svc in services.items():
             instrument = getattr(svc, "instrument", None)
             if instrument is not None:
-                instrument(tracer.metrics)
+                instrument(tracer.metrics, sid)
     engine, world = engine_from_scenario(cfg, services,
                                          early_exit=early_exit,
                                          tracer=tracer)

@@ -48,12 +48,12 @@ from typing import Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.serving.engine import (EngineConfig, Request, ServingEngine,
-                                  apply_block_results)
+                                  apply_block_results, group_by_service)
 from repro.serving.policy_bridge import (ServingPolicy, engine_from_scenario,
                                          submit_arrivals)
 from repro.serving.kv_manager import TransferLedger, state_nbytes
 from repro.serving.telemetry import TelemetryLog
-from repro.serving.tracing import Tracer, latency_summary
+from repro.serving.tracing import Tracer, latency_summary, phase
 from repro.sim.env import SimConfig
 
 
@@ -112,6 +112,13 @@ class ClusterEngine:
     def frame(self) -> int:
         return self.engines[0].frame
 
+    @property
+    def metrics(self):
+        """The shared tracer's registry (None with tracing off): the
+        ``fleet`` phase, the cluster's own work outside the engines and
+        services, is observed there."""
+        return self.tracer.metrics if self.tracer is not None else None
+
     def submit(self, cell: int, req: Request) -> None:
         self.engines[cell].submit(req)
 
@@ -132,11 +139,8 @@ class ClusterEngine:
     def apply_handovers(self, events: Sequence[HandoverEvent]
                         ) -> List[HandoverEvent]:
         """Apply the feasible subset of ``events``; returns what moved."""
-        applied = []
-        for ev in events:
-            if self._apply_handover(ev):
-                applied.append(ev)
-        return applied
+        with phase(self.metrics, "fleet", frame=self.frame):
+            return [ev for ev in events if self._apply_handover(ev)]
 
     def _apply_handover(self, ev: HandoverEvent) -> bool:
         src, dst = self.engines[ev.src_cell], self.engines[ev.dst_cell]
@@ -233,15 +237,11 @@ class ClusterEngine:
     def _execute_stacked(self, plans: List[Dict[int, List[Request]]]) -> None:
         """Advance every planned request in ONE ``run_batch`` per service —
         the whole fleet's (cell, node) groups stacked into a single device
-        call per service."""
-        groups: Dict[int, tuple] = {}
-        for eng, plan in zip(self.engines, plans):
-            for target, reqs in plan.items():
-                cost = eng.nodes[target].spec.exec_cost
-                for req in reqs:
-                    reqs_s, costs_s = groups.setdefault(req.service, ([], []))
-                    reqs_s.append(req)
-                    costs_s.append(cost)
+        call per service.  Grouping and write-back are ``fleet`` phases;
+        the services time their block calls themselves."""
+        metrics, frame = self.metrics, self.frame
+        with phase(metrics, "fleet", frame=frame):
+            groups = group_by_service(zip(self.engines, plans))
         for service in sorted(groups):
             reqs, costs = groups[service]
             svc = self.services[service]
@@ -249,7 +249,8 @@ class ClusterEngine:
                 states, qualities = svc.run_batch(
                     [r.state for r in reqs],
                     np.asarray([r.blocks_done for r in reqs], dtype=int))
-                apply_block_results(reqs, states, qualities, costs)
+                with phase(metrics, "fleet", frame=frame):
+                    apply_block_results(reqs, states, qualities, costs)
             else:
                 block_fn = self._block_fns[service]
                 for req, cost in zip(reqs, costs):
@@ -345,10 +346,10 @@ def cluster_from_scenario(cfg: SimConfig, num_cells: int,
                            or (engine_cfg is not None and engine_cfg.tracing)):
         tracer = Tracer()
     if tracer is not None:
-        for svc in services.values():
+        for sid, svc in services.items():
             instrument = getattr(svc, "instrument", None)
             if instrument is not None:
-                instrument(tracer.metrics)
+                instrument(tracer.metrics, sid)
     engines = []
     for c in range(num_cells):
         engine, world = engine_from_scenario(

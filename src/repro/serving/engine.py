@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.serving.kv_manager import TransferLedger, state_nbytes
 from repro.serving.telemetry import QuantumEvent, TelemetryLog
-from repro.serving.tracing import Tracer, latency_summary
+from repro.serving.tracing import Tracer, latency_summary, phase
 
 
 @dataclasses.dataclass
@@ -82,6 +82,21 @@ def apply_block_results(reqs: List[Request], states: List[Any],
         req.quality = float(quality)
         req.blocks_done += 1
         req.exec_cost += float(cost)
+
+
+def group_by_service(pairs) -> Dict[int, Tuple[List[Request], List[float]]]:
+    """Merge ``(engine, node -> requests)`` plans into the stacked fleet
+    batch of each service: its requests, and the execution cost of the
+    node each one runs on, in plan order."""
+    groups: Dict[int, Tuple[List[Request], List[float]]] = {}
+    for eng, plan in pairs:
+        for target, reqs in plan.items():
+            cost = eng.nodes[target].spec.exec_cost
+            for req in reqs:
+                reqs_s, costs_s = groups.setdefault(req.service, ([], []))
+                reqs_s.append(req)
+                costs_s.append(cost)
+    return groups
 
 
 @dataclasses.dataclass
@@ -298,6 +313,12 @@ class ServingEngine:
         self._q_leaves = 0
         self._q_throttled = 0
         self._batch_rids: set = set()
+
+    @property
+    def metrics(self):
+        """The tracer's registry, where the wall-clock phases of the
+        quantum are observed (None with tracing off)."""
+        return self.tracer.metrics if self.tracer is not None else None
 
     # -- request lifecycle -----------------------------------------------------
 
@@ -618,17 +639,20 @@ class ServingEngine:
         """Open a quantum: resilience pre-passes + admission (strict no-ops
         for a healthy fault state and/or no RecoveryConfig, keeping the
         zero-fault path frame-for-frame identical to the pre-fault engine),
-        then reset the per-quantum scratch the block steps accumulate into."""
-        self._shed_deadlines()
-        self._handle_node_failures()
-        self._admit()
-        self._degrade()
-        self._q_loads = np.zeros(len(self.nodes), dtype=int)
-        self._q_exec = 0.0
-        self._q_trans = 0.0
-        self._q_delivered = []
-        self._q_steps = 0
-        self._q_planned = 0
+        then reset the per-quantum scratch the block steps accumulate into.
+        Timed as the ``admission`` phase."""
+        with phase(self.metrics, "admission", frame=self.frame,
+                   cell=self.cell_id):
+            self._shed_deadlines()
+            self._handle_node_failures()
+            self._admit()
+            self._degrade()
+            self._q_loads = np.zeros(len(self.nodes), dtype=int)
+            self._q_exec = 0.0
+            self._q_trans = 0.0
+            self._q_delivered = []
+            self._q_steps = 0
+            self._q_planned = 0
 
     def plan_step(self, final: bool = True) -> Dict[int, List[Request]]:
         """One placement pass over the active set: batched policy decision,
@@ -652,6 +676,14 @@ class ServingEngine:
         begin = getattr(self.placement_fn, "begin_quantum", None)
         if begin is not None:
             begin(self)
+        with phase(self.metrics, "placement", frame=self.frame,
+                   cell=self.cell_id):
+            return self._place(final)
+
+    def _place(self, final: bool) -> Dict[int, List[Request]]:
+        """:meth:`plan_step` after the policy's decision: the placement
+        loop, transmission charging, the tracer's compute spans and the
+        batch-join count."""
         loads = self._q_loads
         delivered: List[Request] = []
         assigned: Dict[int, List[Request]] = {}
@@ -731,7 +763,13 @@ class ServingEngine:
         """Close one block step: post-execution delivery checks, the
         downlink leg, and completion bookkeeping — delivered requests vacate
         their batch slot immediately (the continuous scheduler refills it
-        next step)."""
+        next step).  Timed as the ``accounting`` phase."""
+        with phase(self.metrics, "accounting", frame=self.frame,
+                   cell=self.cell_id):
+            return self._finish_step(assigned)
+
+    def _finish_step(self, assigned: Dict[int, List[Request]]
+                     ) -> List[Request]:
         assert self._step_scratch is not None, "finish_step without plan_step"
         delivered = self._step_scratch
         self._step_scratch = None
@@ -773,7 +811,13 @@ class ServingEngine:
     def end_quantum(self) -> Dict[str, float]:
         """Close a quantum: the telemetry event, counter resets, and the
         frame advance.  Returns the same per-quantum stats dict as the
-        former monolithic ``end_step``."""
+        former monolithic ``end_step``.  Timed as the ``accounting``
+        phase."""
+        with phase(self.metrics, "accounting", frame=self.frame,
+                   cell=self.cell_id):
+            return self._end_quantum()
+
+    def _end_quantum(self) -> Dict[str, float]:
         loads = self._q_loads
         delivered = self._q_delivered
         if self.telemetry is not None:
@@ -837,9 +881,11 @@ class ServingEngine:
 
     def end_step(self, assigned: Dict[int, List[Request]]) -> Dict[str, float]:
         """Second half of a quantum-mode quantum: :meth:`finish_step` +
-        :meth:`end_quantum`."""
-        self.finish_step(assigned)
-        return self.end_quantum()
+        :meth:`end_quantum`, timed as ONE ``accounting`` phase."""
+        with phase(self.metrics, "accounting", frame=self.frame,
+                   cell=self.cell_id):
+            self._finish_step(assigned)
+            return self._end_quantum()
 
     def step(self) -> Dict[str, float]:
         if self.cfg.scheduling == "continuous":

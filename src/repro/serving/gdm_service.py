@@ -33,6 +33,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels.ops import resolve_impl
 from repro.models.gdm import (LATENT_CHANNELS, init_gdm, make_schedule,
                               quality_per_block, run_block_batched)
+from repro.serving.tracing import phase
 
 
 def default_gdm_impl(impl: Optional[str], cfg: ModelConfig) -> str:
@@ -117,13 +118,12 @@ class GDMService:
                                     total_steps=total, impl=self.impl,
                                     mesh=mesh, batch_axis=batch_axis)
         # observability (repro.serving.tracing): instrument() attaches a
-        # MetricsRegistry; _call_runner then wall-clocks every compiled call
-        # and flags compile events by first-seen (impl, bucket) shape key
-        # (XLA recompiles are shape-keyed).  None -> the raw runner call.
+        # MetricsRegistry; the block calls then time their phases into it,
+        # and _call_runner flags compile events by first-seen (impl, bucket)
+        # shape key (XLA recompiles are shape-keyed).  None -> untimed.
         self.metrics = None
+        self.service_id = -1
         self._compiled_keys: set = set()
-        self._sample_every = 16
-        self._steady_calls = 0
 
         # Ω(k): measured SSIM-vs-final per block (Fig. 1 protocol), forced
         # monotone — measured curves are monotone in expectation only
@@ -142,48 +142,44 @@ class GDMService:
             _, replicated = batch_shardings(mesh, batch_axis)
             self.params = jax.device_put(self.params, replicated)
 
-    def instrument(self, metrics, sample_every: int = 16) -> None:
-        """Attach a :class:`repro.serving.tracing.MetricsRegistry`: jitted
-        runner calls are wall-clocked into ``gdm_run_batch_ms`` (steady
-        state) or ``gdm_compile_ms`` (first call at a new (impl, bucket)
-        shape — a compile event, also counted in ``gdm_compile_events``).
-        Attach BEFORE serving traffic so the first-seen set is honest.
-
-        Honest wall-clock needs ``jax.block_until_ready``, and forcing
-        that sync on EVERY call defeats async dispatch overlap — so
-        steady-state calls are only timed every ``sample_every``-th call
-        (compile events are always timed); the rest dispatch untouched.
-        ``sample_every=1`` times everything."""
+    def instrument(self, metrics, service: int) -> None:
+        """Attach a :class:`repro.serving.tracing.MetricsRegistry` for the
+        service with id ``service``.  Every non-empty block call (``run_batch``
+        and :meth:`SlotBatch.step`) then times its phases ``stage_in``,
+        ``launch``, ``device_wait`` and ``readback`` into it
+        (:func:`repro.serving.tracing.phase`), tagged with the service, live
+        rows and bucket.  A first call at a new (impl, bucket) shape is a
+        compile event: counted in ``gdm_compile_events`` and timed to its
+        end in ``gdm_compile_ms``.  Attach BEFORE serving traffic so the
+        first-seen set is honest."""
         self.metrics = metrics
-        self._sample_every = max(int(sample_every), 1)
-        self._steady_calls = 0
+        self.service_id = int(service)
 
     def _call_runner(self, latent_buf, prompt_buf, idx_buf):
         """The one seam both batch paths (run_batch / SlotBatch.step) issue
         their device call through; uninstrumented it IS the raw call."""
-        if self.metrics is None:
-            return self._runner(self.params, latent_buf, prompt_buf, idx_buf)
         m = self.metrics
-        key = (self.impl, int(latent_buf.shape[0]))
-        first = key not in self._compiled_keys
+        if m is None:
+            return self._runner(self.params, latent_buf, prompt_buf, idx_buf)
         m.counter("gdm_runner_calls").inc()
-        m.gauge("gdm_last_batch_rows").set(latent_buf.shape[0])
-        if not first:
-            self._steady_calls += 1
-            if self._steady_calls % self._sample_every:
-                return self._runner(self.params, latent_buf, prompt_buf,
-                                    idx_buf)
+        key = (self.impl, int(latent_buf.shape[0]))
+        if key in self._compiled_keys:
+            return self._runner(self.params, latent_buf, prompt_buf, idx_buf)
         t0 = time.perf_counter()
         out = self._runner(self.params, latent_buf, prompt_buf, idx_buf)
         jax.block_until_ready(out)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        if first:
-            self._compiled_keys.add(key)
-            m.counter("gdm_compile_events").inc()
-            m.histogram("gdm_compile_ms").observe(dt_ms)
-        else:
-            m.histogram("gdm_run_batch_ms").observe(dt_ms)
+        self._compiled_keys.add(key)
+        m.counter("gdm_compile_events").inc()
+        m.histogram("gdm_compile_ms").observe((time.perf_counter() - t0) * 1e3)
         return out
+
+    def _wait(self, out, meta: dict) -> None:
+        """With a registry attached: the ``device_wait`` phase, the host
+        blocked on the block program.  Untraced, the read-back's
+        ``np.asarray`` stays the only sync."""
+        if self.metrics is not None:
+            with phase(self.metrics, "device_wait", **meta):
+                jax.block_until_ready(out)
 
     # -- engine contracts -----------------------------------------------------
 
@@ -239,28 +235,33 @@ class GDMService:
             # row or bump batch_calls
             return [], self.omega[np.asarray(block_idxs, dtype=int) + 1]
         bucket = self._bucket(b)
-        buf = self._buffers.get(bucket)
-        if buf is None:
-            hw2 = self.cfg.latent_hw ** 2
-            buf = self._buffers[bucket] = (
-                np.zeros((bucket, hw2, LATENT_CHANNELS), np.float32),
-                np.zeros((bucket, self.prompt_len), np.int32),
-                np.zeros((bucket,), np.int32))
-        latent_buf, prompt_buf, idx_buf = buf
-        for i, s in enumerate(states):
-            latent_buf[i] = s["latent"]
-            prompt_buf[i] = s["prompt"]
-        idx_buf[:b] = np.asarray(block_idxs, np.int32)
-        idx_buf[b:] = 0
+        m = self.metrics
+        meta = {"service": self.service_id, "rows": b, "bucket": bucket}
+        with phase(m, "stage_in", **meta):
+            buf = self._buffers.get(bucket)
+            if buf is None:
+                hw2 = self.cfg.latent_hw ** 2
+                buf = self._buffers[bucket] = (
+                    np.zeros((bucket, hw2, LATENT_CHANNELS), np.float32),
+                    np.zeros((bucket, self.prompt_len), np.int32),
+                    np.zeros((bucket,), np.int32))
+            latent_buf, prompt_buf, idx_buf = buf
+            for i, s in enumerate(states):
+                latent_buf[i] = s["latent"]
+                prompt_buf[i] = s["prompt"]
+            idx_buf[:b] = np.asarray(block_idxs, np.int32)
+            idx_buf[b:] = 0
         # pad rows keep whatever latents a previous call staged (plus a
         # valid block 0 index) — per-sample independence makes them inert
-        latent, x0 = self._call_runner(latent_buf, prompt_buf, idx_buf)
+        with phase(m, "launch", **meta):
+            result = self._call_runner(latent_buf, prompt_buf, idx_buf)
         self.batch_calls += 1
-        latent = np.asarray(latent)
-        x0 = np.asarray(x0)
-        out = [dict(s, latent=latent[i], x0=x0[i])
-               for i, s in enumerate(states)]
-        return out, self.omega[np.asarray(block_idxs) + 1]
+        self._wait(result, meta)
+        with phase(m, "readback", **meta):
+            latent, x0 = (np.asarray(a) for a in result)
+            out = [dict(s, latent=latent[i], x0=x0[i])
+                   for i, s in enumerate(states)]
+            return out, self.omega[np.asarray(block_idxs) + 1]
 
     def block_fn(self, state: Dict, block_idx: int) -> Tuple[Dict, float]:
         """Scalar fallback (legacy per-request path): batch of one."""
@@ -335,56 +336,64 @@ class SlotBatch:
         if not items:
             return [], svc.omega[np.asarray([], dtype=int) + 1]
         bucket = svc._bucket(len(items))
-        if bucket != self.bucket:
-            # bucket churn: compact into the new bucket's buffers (every
-            # row restages below via the residency check)
-            self.bucket = bucket
-            self.rows = {}
-            self._free = []
-            self._latent_of = {}
-        latent_buf, prompt_buf, idx_buf = self._buffers_for(bucket)
-        # leaves: free the rows of rids not planned this step (a request
-        # skipping a step loses residency and restages when it returns)
-        planned = {rid for rid, _, _ in items}
-        for rid in [r for r in self.rows if r not in planned]:
-            self._free.append(self.rows.pop(rid))
-            self._latent_of.pop(rid, None)
-        self._free.sort(reverse=True)              # reuse lowest rows first
-        # joins (and residency-check failures): stage their rows
-        next_row = len(self.rows) + len(self._free)
-        for rid, state, _ in items:
-            row = self.rows.get(rid)
-            resident = row is not None and \
-                state["latent"] is self._latent_of.get(rid)
-            if row is None:
-                if self._free:
-                    row = self._free.pop()
-                else:
-                    row = next_row
-                    next_row += 1
-                self.rows[rid] = row
-            if not resident:
-                latent_buf[row] = state["latent"]
-                prompt_buf[row] = state["prompt"]
-                self.rows_staged += 1
-        idx_buf[:] = 0                             # pad rows: valid block 0
-        for (rid, _, k) in items:
-            idx_buf[self.rows[rid]] = k
-        latent_out, x0 = svc._call_runner(latent_buf, prompt_buf, idx_buf)
+        m = svc.metrics
+        meta = {"service": svc.service_id, "rows": len(items),
+                "bucket": bucket}
+        with phase(m, "stage_in", **meta):
+            if bucket != self.bucket:
+                # bucket churn: compact into the new bucket's buffers
+                # (every row restages below via the residency check)
+                self.bucket = bucket
+                self.rows = {}
+                self._free = []
+                self._latent_of = {}
+            latent_buf, prompt_buf, idx_buf = self._buffers_for(bucket)
+            # leaves: free the rows of rids not planned this step (a
+            # request skipping a step loses residency and restages when it
+            # returns)
+            planned = {rid for rid, _, _ in items}
+            for rid in [r for r in self.rows if r not in planned]:
+                self._free.append(self.rows.pop(rid))
+                self._latent_of.pop(rid, None)
+            self._free.sort(reverse=True)          # reuse lowest rows first
+            # joins (and residency-check failures): stage their rows
+            next_row = len(self.rows) + len(self._free)
+            for rid, state, _ in items:
+                row = self.rows.get(rid)
+                resident = row is not None and \
+                    state["latent"] is self._latent_of.get(rid)
+                if row is None:
+                    if self._free:
+                        row = self._free.pop()
+                    else:
+                        row = next_row
+                        next_row += 1
+                    self.rows[rid] = row
+                if not resident:
+                    latent_buf[row] = state["latent"]
+                    prompt_buf[row] = state["prompt"]
+                    self.rows_staged += 1
+            idx_buf[:] = 0                         # pad rows: valid block 0
+            for (rid, _, k) in items:
+                idx_buf[self.rows[rid]] = k
+        with phase(m, "launch", **meta):
+            result = svc._call_runner(latent_buf, prompt_buf, idx_buf)
         svc.batch_calls += 1
         self.device_calls += 1
-        latent_out = np.asarray(latent_out)
-        x0 = np.asarray(x0)
-        out: List[Dict] = []
-        for rid, state, _ in items:
-            row = self.rows[rid]
-            # masked write-back: only planned rows advance in the staging
-            # buffer; the returned view is the residency token for next step
-            latent_buf[row] = latent_out[row]
-            self._latent_of[rid] = latent_row = latent_out[row]
-            out.append(dict(state, latent=latent_row, x0=x0[row]))
-        ks = np.asarray([k for _, _, k in items], dtype=int)
-        return out, svc.omega[ks + 1]
+        svc._wait(result, meta)
+        with phase(m, "readback", **meta):
+            latent_out, x0 = (np.asarray(a) for a in result)
+            out: List[Dict] = []
+            for rid, state, _ in items:
+                row = self.rows[rid]
+                # masked write-back: only planned rows advance in the
+                # staging buffer; the returned view is the residency token
+                # for next step
+                latent_buf[row] = latent_out[row]
+                self._latent_of[rid] = latent_row = latent_out[row]
+                out.append(dict(state, latent=latent_row, x0=x0[row]))
+            ks = np.asarray([k for _, _, k in items], dtype=int)
+            return out, svc.omega[ks + 1]
 
 
 def make_gdm_services(num_services: int, key, *, num_blocks: int = 4,

@@ -34,7 +34,6 @@ engine with the sim's idle-gated Bernoulli arrival semantics).
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -43,6 +42,7 @@ import numpy as np
 from repro.core.learn_gdm import obs_history_window
 from repro.serving.engine import (EngineConfig, NodeExecutor, NodeSpec,
                                   Request, ServingEngine)
+from repro.serving.tracing import phase
 from repro.sim.env import (IDLE, PENDING, SimConfig, draw_static_world,
                            grid_trans_cost)
 
@@ -103,6 +103,24 @@ class ServingPolicy:
     # -- once per scheduling quantum ------------------------------------------
 
     def begin_quantum(self, engine: ServingEngine) -> None:
+        """One batched decision for every UE slot, timed as two phases:
+        ``policy_obs`` (:meth:`_observe`) and ``policy_act_batch`` (the
+        policy's act)."""
+        metrics = engine.metrics
+        meta = {"frame": engine.frame, "cell": engine.cell_id}
+        with phase(metrics, "policy_obs", **meta):
+            view, obs_hist = self._observe(engine)
+        with phase(metrics, "policy_act_batch", **meta):
+            acts = self.policy.act_batch(view, obs_hist)
+        self._actions = np.asarray(acts)[0].astype(int)
+        if self.record:
+            self.trace.append((engine.frame,
+                               None if obs_hist is None else obs_hist.copy(),
+                               self._actions.copy()))
+
+    def _observe(self, engine: ServingEngine):
+        """The policy's input this quantum: the slot view and the
+        observation history window (None for a policy that needs none)."""
         cfg = self.cfg
         u, n = cfg.num_ues, cfg.num_bs
         quality = np.zeros(u)
@@ -155,21 +173,7 @@ class ServingPolicy:
                          blocks[None],
                          node_up=up[None] if engine._fault_active
                          and not up.all() else None)
-        if engine.tracer is not None:
-            # wall-clock the batched decision into the metrics registry
-            # (observation only; the action path is untouched)
-            t0 = time.perf_counter()
-            acts = self.policy.act_batch(view, obs_hist)
-            engine.tracer.metrics.histogram("policy_act_batch_ms").observe(
-                (time.perf_counter() - t0) * 1e3)
-            engine.tracer.metrics.counter("policy_act_batch_calls").inc()
-        else:
-            acts = self.policy.act_batch(view, obs_hist)
-        self._actions = np.asarray(acts)[0].astype(int)
-        if self.record:
-            self.trace.append((engine.frame,
-                               None if obs_hist is None else obs_hist.copy(),
-                               self._actions.copy()))
+        return view, obs_hist
 
     def __call__(self, req: Request, loads: np.ndarray) -> int:
         # controller convention: 0 = null action (-1 to the engine)

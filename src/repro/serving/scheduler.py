@@ -48,7 +48,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.serving.engine import ServingEngine, apply_block_results
+from repro.serving.engine import (ServingEngine, apply_block_results,
+                                  group_by_service)
+from repro.serving.tracing import phase
 
 
 @dataclasses.dataclass
@@ -136,20 +138,16 @@ def _execute_step(cluster, pairs: List[Tuple[ServingEngine, Dict]],
     batches stacked into one device call per service, like
     ``ClusterEngine._execute_stacked``, but routed through the services'
     slot-resident batches (``slot_batch``) when the scheduler is in
-    join/leave mode, so continuing requests are not restaged every step."""
+    join/leave mode, so continuing requests are not restaged every step.
+    Grouping and write-back are ``fleet`` phases, as in the quantum path."""
     if not cluster.stacked:
         for eng, plan in pairs:
             for target, reqs in plan.items():
                 eng.nodes[target].run_batch(reqs)
         return
-    groups: Dict[int, tuple] = {}
-    for eng, plan in pairs:
-        for target, reqs in plan.items():
-            cost = eng.nodes[target].spec.exec_cost
-            for req in reqs:
-                reqs_s, costs_s = groups.setdefault(req.service, ([], []))
-                reqs_s.append(req)
-                costs_s.append(cost)
+    metrics, frame = cluster.metrics, pairs[0][0].frame
+    with phase(metrics, "fleet", frame=frame):
+        groups = group_by_service(pairs)
     if cluster.tracer is not None:
         # stacked batch size per step into the metrics registry (how full
         # the fused device call runs under continuous scheduling)
@@ -162,12 +160,14 @@ def _execute_step(cluster, pairs: List[Tuple[ServingEngine, Dict]],
         if slot_batch is not None:
             states, qualities = slot_batch().step(
                 [(r.rid, r.state, r.blocks_done) for r in reqs])
-            apply_block_results(reqs, states, qualities, costs)
+            with phase(metrics, "fleet", frame=frame):
+                apply_block_results(reqs, states, qualities, costs)
         elif hasattr(svc, "run_batch"):
             states, qualities = svc.run_batch(
                 [r.state for r in reqs],
                 np.asarray([r.blocks_done for r in reqs], dtype=int))
-            apply_block_results(reqs, states, qualities, costs)
+            with phase(metrics, "fleet", frame=frame):
+                apply_block_results(reqs, states, qualities, costs)
         else:
             block_fn = cluster._block_fns[service]
             for req, cost in zip(reqs, costs):

@@ -14,13 +14,45 @@ per-(cell, node) timelines:
   (uplink / migration / handover / failover / downlink / shard) with its
   bytes and cost.
 
-Time is the engine's *logical* clock: one scheduling quantum = one frame,
-subdivided by the continuous scheduler's block steps (and shifted by the
-per-cell quantum skew).  Wall-clock observation rides separately in the
-:class:`MetricsRegistry` (counters / gauges / fixed-bucket histograms with
-exact p50/p95/p99): :meth:`repro.serving.gdm_service.GDMService.instrument`
-hooks compile events and per-compiled-call wall time around the jitted
-runners, and the policy bridge times its batched decisions.
+Two clocks, one per kind of record:
+
+* **frames** — the request spans above run on the engine's *logical*
+  clock: one scheduling quantum = one frame, subdivided by the continuous
+  scheduler's block steps (and shifted by the per-cell quantum skew).
+  They attribute frames and time nothing.
+* **wall time on the profiler's clock** — :func:`phase` times one phase
+  of the serving loop where the work happens: it opens a
+  ``jax.profiler.TraceAnnotation`` named ``PHASE_PREFIX + name`` (on the
+  profiler's host plane, the clock the device trace shares; keyword
+  metadata lands as the event's stats) and observes the phase's wall
+  milliseconds (``time.perf_counter``) into the :class:`MetricsRegistry`
+  histogram ``<name>_ms``.  The phases are leaves — none opens inside
+  another — so each histogram is its layer's self time:
+
+  ========================  ==============================================
+  ``admission``             ``ServingEngine.begin_quantum``: deadlines,
+                            failures, admission, degradation, scratch
+  ``policy_obs``            the policy bridge's observation and slot view
+  ``policy_act_batch``      the policy's batched ``act_batch`` decision
+  ``placement``             ``plan_step`` after the decision: placement
+                            loop, transmission charging, span hooks
+  ``accounting``            ``finish_step`` / ``end_quantum``: delivery,
+                            downlink, telemetry event, frame advance
+  ``fleet``                 the cluster's handovers, grouping of the plans
+                            by service and write-back of block results
+  ``stage_in``              a block call's row copies into staging buffers
+  ``launch``                the jitted call's dispatch (host-to-device
+                            copy of the staged buffers included)
+  ``device_wait``           the host blocked on the block program
+  ``readback``              device-to-host copy of the outputs, the output
+                            states and the Ω gather
+  ========================  ==============================================
+
+  With no registry attached (tracing off) :func:`phase` returns one
+  shared do-nothing context: no clock read, no annotation, no record.
+  The other wall-clock record is ``gdm_compile_ms``/``gdm_compile_events``
+  (first call at a new bucket, :meth:`GDMService.instrument
+  <repro.serving.gdm_service.GDMService.instrument>`).
 
 Exports:
 
@@ -47,9 +79,12 @@ invariant the tests pin.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.telemetry import validate
@@ -203,7 +238,8 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Named counters / gauges / histograms (one flat namespace)."""
+    """Named counters / gauges / histograms (one flat namespace); the
+    wall-clock phases (:func:`phase`) observe into its histograms."""
 
     def __init__(self):
         self.counters: Dict[str, Counter] = {}
@@ -236,6 +272,41 @@ class MetricsRegistry:
             "histograms": {k: h.to_json()
                            for k, h in sorted(self.histograms.items())},
         }
+
+
+# -- wall-clock phases ---------------------------------------------------------
+
+# the name prefix of every phase span in the profiler's host trace
+PHASE_PREFIX = "serve/"
+
+# what phase() returns with no registry attached: one shared, reusable
+# context that does nothing
+NO_PHASE = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def _timed_phase(hist: Histogram, name: str, meta: dict):
+    # the clock brackets the span: an interpreter pause (a garbage
+    # collection, a thread switch) inside the span's own enter or exit
+    # still counts as the phase's time
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **meta):
+            yield
+    finally:
+        hist.observe((time.perf_counter() - t0) * 1e3)
+
+
+def phase(metrics: Optional[MetricsRegistry], name: str, **meta):
+    """Time one phase of the serving loop (see the module docstring): a
+    context manager that writes the span ``PHASE_PREFIX + name`` with
+    ``meta`` as its stats into the profiler's trace and observes its wall
+    milliseconds into ``metrics``'s histogram ``<name>_ms``, also when the
+    phase exits by an exception.  ``metrics=None`` returns
+    :data:`NO_PHASE`."""
+    if metrics is None:
+        return NO_PHASE
+    return _timed_phase(metrics.histogram(f"{name}_ms"), name, meta)
 
 
 def latency_summary(lat: Sequence[float]) -> Dict[str, float]:

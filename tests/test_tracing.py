@@ -24,9 +24,9 @@ from repro.serving import (RecoveryConfig, TelemetryLog, TransferLedger,
                            cluster_from_scenario, serve_fleet)
 from repro.serving.engine import EngineConfig
 from repro.serving.scheduler import SchedulerConfig
-from repro.serving.tracing import (SEGMENTS, TRACE_SCHEMA_VERSION, Histogram,
-                                   MetricsRegistry, Tracer, latency_summary,
-                                   validate_trace)
+from repro.serving.tracing import (NO_PHASE, SEGMENTS, TRACE_SCHEMA_VERSION,
+                                   Histogram, MetricsRegistry, Tracer,
+                                   latency_summary, phase, validate_trace)
 from repro.sim.faults import fault_trace
 from repro.sim.scenarios import get_scenario
 from repro.sim.workloads import fleet_trace
@@ -208,16 +208,16 @@ def test_trace_doc_round_trip_with_populated_metrics():
     # (regression: from_json used to silently drop histogram snapshots)
     _, _, _, tracer, _ = _run_fleet(tracing=True, frames=4)
     tracer.metrics.counter("gdm_runner_calls").inc(3)
-    h = tracer.metrics.histogram("gdm_run_batch_ms")
+    h = tracer.metrics.histogram("launch_ms")
     for v in (0.7, 2.5, 40.0, 900.0):
         h.observe(v)
     doc = json.loads(json.dumps(tracer.to_json()))
-    assert doc["metrics"]["histograms"]["gdm_run_batch_ms"]["count"] == 4
+    assert doc["metrics"]["histograms"]["launch_ms"]["count"] == 4
     rt = Tracer.from_json(doc)
     assert rt.to_json() == doc
     # the restored histogram is a frozen summary: stored stats answer
     # exactly, and observing into it resumes live mode from empty
-    frozen = rt.metrics.histogram("gdm_run_batch_ms")
+    frozen = rt.metrics.histogram("launch_ms")
     assert frozen.count == 4 and frozen.max == 900.0
     assert frozen.percentile(95) == h.percentile(95)
     with pytest.raises(ValueError):
@@ -310,10 +310,12 @@ def test_latency_summary_matches_numpy():
 def test_policy_bridge_decision_metrics_recorded():
     out, _, _, tracer, _ = _run_fleet(
         _POLICY_FACTORIES["greedy-bridge"](), tracing=True)
-    mj = tracer.metrics.to_json()
-    assert mj["counters"]["policy_act_batch_calls"] > 0
-    assert mj["histograms"]["policy_act_batch_ms"]["count"] \
-        == mj["counters"]["policy_act_batch_calls"]
+    hist = tracer.metrics.to_json()["histograms"]
+    # one observation phase and one decision per cell and quantum
+    assert hist["policy_act_batch_ms"]["count"] == CELLS * FRAMES
+    assert hist["policy_obs_ms"]["count"] \
+        == hist["policy_act_batch_ms"]["count"]
+    assert hist["policy_act_batch_ms"]["total"] > 0
 
 
 @pytest.mark.slow
@@ -324,7 +326,7 @@ def test_gdm_service_compile_and_call_metrics():
 
     svc = GDMService(jax.random.PRNGKey(0), num_blocks=2, ref_prompts=2)
     m = MetricsRegistry()
-    svc.instrument(m, sample_every=1)   # time EVERY call for exact counts
+    svc.instrument(m, 0)
     rng = np.random.default_rng(0)
     states = [svc.init_state(rng) for _ in range(2)]
     ks = np.zeros(2, dtype=int)
@@ -332,7 +334,156 @@ def test_gdm_service_compile_and_call_metrics():
     svc.run_batch(states, ks)          # steady state
     assert m.counter("gdm_runner_calls").value == 2
     assert m.counter("gdm_compile_events").value == 1
-    assert m.histogram("gdm_run_batch_ms").count == 1
     assert m.histogram("gdm_compile_ms").count == 1
+    # every call is timed: its dispatch and the host's wait on the device
+    assert m.histogram("launch_ms").count == 2
+    assert m.histogram("device_wait_ms").count == 2
     svc.run_batch(states + [svc.init_state(rng)] * 2, np.zeros(4, dtype=int))
     assert m.counter("gdm_compile_events").value == 2   # new bucket = 4
+
+
+# -- wall-clock phases ---------------------------------------------------------
+
+ENGINE_PHASES = ("admission", "policy_obs", "policy_act_batch", "placement",
+                 "accounting")
+SERVICE_PHASES = ("stage_in", "launch", "device_wait", "readback")
+PHASES = ENGINE_PHASES + ("fleet",) + SERVICE_PHASES
+
+
+def test_phase_without_registry_is_the_shared_noop():
+    assert phase(None, "admission", frame=3, cell=1) is NO_PHASE
+    assert phase(None, "launch") is NO_PHASE
+    with phase(None, "admission", frame=3, cell=1):
+        pass
+
+
+def test_phase_observes_once_per_exit_also_by_exception():
+    m = MetricsRegistry()
+    with phase(m, "admission", frame=0, cell=0):
+        pass
+    with pytest.raises(KeyError):
+        with phase(m, "admission", frame=1, cell=0):
+            raise KeyError("raised inside the phase")
+    h = m.histogram("admission_ms")
+    assert h.count == 2
+    assert all(v > 0 for v in h.values)
+    assert set(m.histograms) == {"admission_ms"}
+    assert not m.counters and not m.gauges
+
+
+def _states(svc, rng, n):
+    return [svc.init_state(rng) for _ in range(n)]
+
+
+def test_instrumented_service_phases_and_bit_identical_outputs(monkeypatch):
+    import jax
+
+    from repro.serving.gdm_service import GDMService
+
+    syncs = []
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: syncs.append(1) or block(x))
+
+    key = jax.random.PRNGKey(1)
+    traced = GDMService(key, num_blocks=2, ref_prompts=2)
+    plain = GDMService(key, num_blocks=2, ref_prompts=2)
+    m = MetricsRegistry()
+    traced.instrument(m, 2)
+    rng = np.random.default_rng(0)
+    states = _states(traced, rng, 3)
+    ks = np.asarray([0, 1, 0])
+    calls = 0
+    for n in (3, 3, 2):                    # a new bucket, then steady calls
+        out_t, q_t = traced.run_batch(states[:n], ks[:n])
+        synced = len(syncs)
+        out_p, q_p = plain.run_batch(states[:n], ks[:n])
+        assert len(syncs) == synced, "the untraced call forced a sync"
+        calls += 1
+        assert np.array_equal(q_t, q_p)
+        for a, b in zip(out_t, out_p):
+            assert np.array_equal(a["latent"], b["latent"])
+            assert np.array_equal(a["x0"], b["x0"])
+    assert traced.run_batch([], np.zeros(0, dtype=int))[0] == []
+    # the slot-resident path: a join, a continuing row, a leave
+    items = [(7, states[0], 0), (8, states[1], 1)]
+    for step in range(2):
+        out_t, _ = traced.slot_batch().step(items)
+        synced = len(syncs)
+        out_p, _ = plain.slot_batch().step(items)
+        assert len(syncs) == synced, "the untraced step forced a sync"
+        calls += 1
+        for a, b in zip(out_t, out_p):
+            assert np.array_equal(a["latent"], b["latent"])
+            assert np.array_equal(a["x0"], b["x0"])
+        items = [(7, out_t[0], 1)]
+    for name in SERVICE_PHASES:
+        assert m.histogram(f"{name}_ms").count == calls, name
+    assert plain.metrics is None and plain.batch_calls == traced.batch_calls
+
+
+@pytest.mark.parametrize("mode", ["quantum", "continuous"])
+def test_phase_counts_on_traced_fleet(mode):
+    kw = {}
+    if mode == "continuous":
+        kw = dict(engine_cfg=EngineConfig(scheduling="continuous", seed=0),
+                  sched=SchedulerConfig(join_leave=True))
+    _, _, _, tracer, _ = _run_fleet(_POLICY_FACTORIES["greedy-bridge"](),
+                                    tracing=True, **kw)
+    hist = tracer.metrics.histograms
+    quanta = CELLS * FRAMES
+    assert hist["admission_ms"].count == quanta
+    assert hist["policy_obs_ms"].count == hist["policy_act_batch_ms"].count
+    if mode == "quantum":
+        # one placement pass and one accounting phase per cell and quantum
+        for name in ("placement", "accounting", "policy_act_batch"):
+            assert hist[f"{name}_ms"].count == quanta, name
+    else:
+        # a continuous quantum runs several block steps
+        assert hist["placement_ms"].count > quanta
+        assert hist["accounting_ms"].count > quanta
+    assert hist["fleet_ms"].count > 0
+    for name in ENGINE_PHASES + ("fleet",):
+        assert hist[f"{name}_ms"].total > 0, name
+
+
+@pytest.mark.timeout(120)
+def test_phases_land_on_the_profiler_host_plane(tmp_path):
+    import jax
+
+    from repro.serving.gdm_service import GDMService
+    from repro.serving.tracing import PHASE_PREFIX
+
+    from test_cluster import LinearService
+
+    cfg = get_scenario("smoke")
+    # the DiT serves service 2, which most of the trace's UEs request
+    services = {0: LinearService(), 1: LinearService(),
+                2: GDMService(jax.random.PRNGKey(2), ref_prompts=2)}
+    cluster = cluster_from_scenario(
+        cfg, CELLS, services, tracing=True,
+        policy_factory=_POLICY_FACTORIES["greedy-bridge"]())
+    fleet = fleet_trace(cfg, 6, CELLS, workload="flash-crowd", seed=5,
+                        handover_rate=0.1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        serve_fleet(cluster, fleet, services, seed=0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    stats = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(PHASE_PREFIX):
+                    stats.setdefault(e.name[len(PHASE_PREFIX):], set()).add(
+                        tuple(sorted(k for k, _ in e.stats)))
+    want = {**{p: ("cell", "frame") for p in ENGINE_PHASES},
+            "fleet": ("frame",),
+            **{p: ("bucket", "rows", "service") for p in SERVICE_PHASES}}
+    assert set(stats) == set(PHASES)
+    for name, keys in want.items():
+        assert stats[name] == {keys}, name
