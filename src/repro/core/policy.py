@@ -27,7 +27,7 @@ test module (the PR 2 equivalence-harness pattern, extended to eval).
 from __future__ import annotations
 
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Hashable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +58,11 @@ class Policy:
     :meth:`fused_key` is the hashable identity of that ``act_fn``'s trace
     (everything baked into it besides ``cfg``) — the compile-cache key in
     :func:`evaluate_fused`.
+
+    :meth:`pool_key` says which policies may decide together: instances
+    with the same non-None key give every env of one stacked
+    :meth:`act_pooled` call the action it would get alone, so a serving
+    fleet decides all their cells in one call (``None``: act alone).
     """
 
     name: str = "policy"
@@ -75,6 +80,15 @@ class Policy:
 
     def fused_key(self) -> Tuple:
         return (type(self).__name__, getattr(self, "variant", None))
+
+    def pool_key(self) -> Optional[Hashable]:
+        return None
+
+    def act_pooled(self, venv: VecEdgeSimulator,
+                   obs_hist: Optional[np.ndarray]) -> np.ndarray:
+        """:meth:`act_batch` for a pool of envs that share a
+        :meth:`pool_key`: each env gets the action it would get alone."""
+        return self.act_batch(venv, obs_hist)
 
 
 class LearnedPolicy(Policy):
@@ -111,6 +125,15 @@ class LearnedPolicy(Policy):
         return (type(self).__name__, self.variant, acfg.num_ues,
                 acfg.num_actions, acfg.history)
 
+    def pool_key(self):
+        # greedy acting is deterministic: one call over stacked envs gives
+        # each env its own action (act_pooled)
+        return (id(self.agent), self.variant)
+
+    def act_pooled(self, venv, obs_hist):
+        mask = variant_action_mask_vec(venv, self.variant)
+        return self.agent.act_rows(obs_hist, mask=mask)
+
 
 class GreedyPoAPolicy(Policy):
     """GR baseline: every block executes at the UE's current PoA; chains
@@ -127,6 +150,9 @@ class GreedyPoAPolicy(Policy):
                              0).astype(jnp.int32)
 
         return (), act_fn
+
+    def pool_key(self):
+        return type(self).__name__
 
 
 class RandomPolicy(Policy):

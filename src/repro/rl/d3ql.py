@@ -113,8 +113,12 @@ class D3QLAgent:
         self.steps = 0
         self.rng = np.random.default_rng(cfg.seed)
         self._update = self._build_update()
-        self._qvals = jax.jit(functools.partial(
-            qnet_apply, num_ues=cfg.num_ues, num_actions=cfg.num_actions))
+        qnet = functools.partial(qnet_apply, num_ues=cfg.num_ues,
+                                 num_actions=cfg.num_actions)
+        self._qvals = jax.jit(qnet)
+        # E one-env forwards in one program (see act_rows)
+        self._qvals_rows = jax.jit(lambda params, obs_hist: jax.lax.map(
+            lambda h: qnet(params, h[None])[0], obs_hist))
 
     # -- acting --------------------------------------------------------------
 
@@ -150,6 +154,20 @@ class D3QLAgent:
             q = np.asarray(self._qvals(self.params, obs_hist))    # (E, U, A)
             if q_rand is not None:
                 q = np.where(explore[:, None, None], q_rand, q)
+        return masked_argmax(q, mask)
+
+    def act_rows(self, obs_hist: np.ndarray,
+                 mask: Optional[np.ndarray] = None) -> np.ndarray:
+        """Greedy acting for E envs in one call, each env's Q-values from
+        a one-env forward: obs_hist (E, H, obs_dim) -> actions (E, U), row
+        ``i`` equal to ``act(obs_hist[i], greedy=True, mask=mask[i])``.
+
+        :meth:`act_batch` runs one E-row forward instead, which the
+        compiler may lower differently from E one-row forwards (on a TPU a
+        one-row matmul becomes an f32 multiply-reduce, a many-row one an
+        MXU pass at default precision), so near-tied actions could flip.
+        """
+        q = np.asarray(self._qvals_rows(self.params, obs_hist))
         return masked_argmax(q, mask)
 
     def decay_epsilon(self) -> None:

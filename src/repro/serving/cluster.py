@@ -5,9 +5,17 @@ scenario-derived world + bridged policy); the :class:`ClusterEngine` runs C
 of them as one fleet:
 
 * **One clock.**  Per scheduling quantum every cell runs
-  ``begin_step`` (admission + placement + transmission charging), then the
-  cluster executes ALL planned blocks, then every cell runs ``end_step``
+  ``begin_quantum`` (admission), the policies decide, every cell runs
+  ``plan_step`` (placement + transmission charging), then the cluster
+  executes ALL planned blocks, then every cell runs ``end_step``
   (delivery + accounting).  Cell frames advance in lock-step.
+* **One act per pool.**  Cells whose bridged policies share a pool key
+  (:meth:`repro.core.policy.Policy.pool_key`: one D3QL agent, say) are
+  decided by ONE batched ``act_pooled`` call per quantum
+  (:class:`~repro.serving.policy_bridge.PolicyPool`), compiled when the
+  cluster is built; any other cell acts alone inside its ``plan_step``.
+  A cell's observation reads only its own engine, so moving every
+  admission ahead of every placement changes no decision.
 * **Stacked execution.**  With ``stacked=True`` (the production path) the
   cluster merges every cell's ``node -> requests`` plan by service and
   advances each service's fleet-wide batch in ONE ``run_batch`` call — for
@@ -50,7 +58,7 @@ import numpy as np
 from repro.serving.engine import (EngineConfig, Request, ServingEngine,
                                   apply_block_results, group_by_service)
 from repro.serving.policy_bridge import (ServingPolicy, engine_from_scenario,
-                                         submit_arrivals)
+                                         policy_pools, submit_arrivals)
 from repro.serving.kv_manager import TransferLedger, state_nbytes
 from repro.serving.telemetry import TelemetryLog
 from repro.serving.tracing import Tracer, latency_summary, phase
@@ -103,6 +111,12 @@ class ClusterEngine:
         self._block_fns = {
             s: (svc.block_fn if hasattr(svc, "block_fn") else svc)
             for s, svc in services.items()}
+        # the cells that decide together in step(), one act per pool; the
+        # continuous scheduler decides per cell and block step instead
+        self.pools = policy_pools(engines) \
+            if engines[0].cfg.scheduling == "quantum" else []
+        self._pooled = {id(eng) for pool in self.pools
+                        for eng in pool.engines}
 
     @property
     def num_cells(self) -> int:
@@ -221,7 +235,12 @@ class ClusterEngine:
         """One scheduling quantum for every cell; returns per-cell stats."""
         if handovers:
             self.apply_handovers(handovers)
-        plans = [eng.begin_step() for eng in self.engines]
+        for eng in self.engines:
+            eng.begin_quantum()
+        for pool in self.pools:
+            pool.act(self.metrics, self.frame)
+        plans = [eng.plan_step(act=id(eng) not in self._pooled)
+                 for eng in self.engines]
         if self.stacked:
             self._execute_stacked(plans)
         else:

@@ -227,9 +227,10 @@ class ServingEngine:
     placement + transmission charging) and :meth:`end_step` (delivery +
     accounting) around the block execution, so a
     :class:`~repro.serving.cluster.ClusterEngine` can stack the execution of
-    many cells into one device call per service; :meth:`step` composes the
-    three for standalone use and is behaviour-identical to the former
-    monolithic quantum.
+    many cells into one device call per service (it runs the halves of
+    :meth:`begin_step` itself, to decide its cells' policies together);
+    :meth:`step` composes the three for standalone use and is
+    behaviour-identical to the former monolithic quantum.
     """
 
     def __init__(self, nodes: List[NodeExecutor], cfg: EngineConfig,
@@ -654,7 +655,8 @@ class ServingEngine:
             self._q_steps = 0
             self._q_planned = 0
 
-    def plan_step(self, final: bool = True) -> Dict[int, List[Request]]:
+    def plan_step(self, final: bool = True, *, act: bool = True
+                  ) -> Dict[int, List[Request]]:
         """One placement pass over the active set: batched policy decision,
         placement, and transmission charging.  Returns the ``node ->
         requests`` execution plan; the caller advances every planned request
@@ -667,13 +669,18 @@ class ServingEngine:
         has ("deliver what exists") instead of waiting.  True for the
         quantum engine's single pass and the continuous scheduler's first
         step (sync equivalence); later continuous steps pass False, where
-        a blocked request just waits for the next quantum's budget."""
+        a blocked request just waits for the next quantum's budget.
+
+        ``act``: False when the policy has already decided this pass — the
+        fleet quantum decides pooled cells together before any of them
+        plans (``repro.serving.policy_bridge.PolicyPool``)."""
         # policy-driven placement hook: a placement_fn exposing
         # ``begin_quantum`` (the ServingPolicy bridge) computes one batched
         # decision for every request slot — rebuilt on the scheduler's
         # cadence (once per quantum in quantum mode, once per block step in
         # continuous mode); the per-request calls below then just read it
-        begin = getattr(self.placement_fn, "begin_quantum", None)
+        begin = getattr(self.placement_fn, "begin_quantum", None) \
+            if act else None
         if begin is not None:
             begin(self)
         with phase(self.metrics, "placement", frame=self.frame,
@@ -874,8 +881,8 @@ class ServingEngine:
 
     def begin_step(self) -> Dict[int, List[Request]]:
         """First half of a quantum-mode quantum: :meth:`begin_quantum` +
-        exactly one :meth:`plan_step` — the composition is what the cluster's
-        lock-step executor and the pre-decomposition tests run."""
+        exactly one :meth:`plan_step` — the composition :meth:`step` and
+        the pre-decomposition tests run."""
         self.begin_quantum()
         return self.plan_step()
 

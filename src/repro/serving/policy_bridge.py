@@ -23,7 +23,10 @@ one-act-per-frame semantics), and once per block step under the
 iteration-level scheduler (``repro.serving.scheduler``), so the
 observation is rebuilt on the scheduler's cadence and mid-quantum
 joins/leaves are visible to the policy; the per-request ``placement_fn``
-calls then read the cached slot actions back.
+calls then read the cached slot actions back.  A fleet quantum
+(``ClusterEngine.step``) pools the act instead: the cells whose policies
+share a pool key are observed one by one and decided by ONE batched call
+(:class:`PolicyPool`), and each bridge takes its row.
 
 Also here: :func:`engine_from_scenario` (build a ServingEngine whose nodes
 ARE the sim world — same W_hat/eps draw, same Y_hat — so a policy trained
@@ -103,24 +106,54 @@ class ServingPolicy:
     # -- once per scheduling quantum ------------------------------------------
 
     def begin_quantum(self, engine: ServingEngine) -> None:
-        """One batched decision for every UE slot, timed as two phases:
-        ``policy_obs`` (:meth:`_observe`) and ``policy_act_batch`` (the
-        policy's act)."""
+        """One batched decision for every UE slot of this cell alone, timed
+        as two phases: ``policy_obs`` (:meth:`observe`) and
+        ``policy_act_batch`` (the policy's act).  A fleet whose cells share
+        a :meth:`~repro.core.policy.Policy.pool_key` decides them together
+        instead (:class:`PolicyPool`)."""
+        view, obs_hist = self.observe(engine)
         metrics = engine.metrics
-        meta = {"frame": engine.frame, "cell": engine.cell_id}
-        with phase(metrics, "policy_obs", **meta):
-            view, obs_hist = self._observe(engine)
-        with phase(metrics, "policy_act_batch", **meta):
+        with phase(metrics, "policy_act_batch", frame=engine.frame,
+                   cell=engine.cell_id):
             acts = self.policy.act_batch(view, obs_hist)
-        self._actions = np.asarray(acts)[0].astype(int)
+        if metrics is not None:
+            metrics.counter("policy_act_cells").inc()
+        self.take(engine.frame, obs_hist, np.asarray(acts)[0])
+
+    def observe(self, engine: ServingEngine):
+        """The policy's input this quantum, timed as ``policy_obs``: the
+        one-env slot view and the observation history window (None for a
+        policy that needs none)."""
+        with phase(engine.metrics, "policy_obs", frame=engine.frame,
+                   cell=engine.cell_id):
+            return self._observe(engine)
+
+    def take(self, frame: int, obs_hist: Optional[np.ndarray],
+             actions: np.ndarray) -> None:
+        """Adopt this quantum's (U,) slot actions, decided on ``obs_hist``
+        — the cell's own act or its row of a pooled one."""
+        self._actions = np.asarray(actions).astype(int)
         if self.record:
-            self.trace.append((engine.frame,
+            self.trace.append((frame,
                                None if obs_hist is None else obs_hist.copy(),
                                self._actions.copy()))
 
+    def blank(self):
+        """A slot view and history window of this bridge's shapes with every
+        slot idle and the observation zero: what compiles the act."""
+        cfg = self.cfg
+        u, n = cfg.num_ues, cfg.num_bs
+        obs_hist = None
+        if self.policy.needs_obs:
+            obs_dim = 2 * n + 2 * u + u * n      # the layout of _observe
+            obs_hist = np.zeros((1, self.policy.history, obs_dim),
+                                np.float32)
+        view = _SlotView(cfg, 1, np.full((1, u), IDLE),
+                         np.zeros((1, u), dtype=int), np.full((1, u), -1),
+                         np.zeros((1, u), dtype=int))
+        return view, obs_hist
+
     def _observe(self, engine: ServingEngine):
-        """The policy's input this quantum: the slot view and the
-        observation history window (None for a policy that needs none)."""
         cfg = self.cfg
         u, n = cfg.num_ues, cfg.num_bs
         quality = np.zeros(u)
@@ -178,6 +211,79 @@ class ServingPolicy:
     def __call__(self, req: Request, loads: np.ndarray) -> int:
         # controller convention: 0 = null action (-1 to the engine)
         return int(self._actions[req.ue]) - 1
+
+
+class PolicyPool:
+    """Cells whose bridged policies share a pool key, decided by ONE
+    ``act_pooled`` call per quantum over their stacked slot views (E = the
+    number of cells).
+
+    A pooled policy gives each env of a stacked ``act_pooled`` call the
+    action it would get alone (:meth:`repro.core.policy.Policy.pool_key`),
+    and a cell's observation reads only its own engine, so row ``i`` of
+    the act is cell ``i``'s own decision.  The act's one shape is compiled
+    when the pool is built, not in the first quantum.
+    """
+
+    def __init__(self, engines: List[ServingEngine]):
+        self.engines = engines
+        self.bridges = [eng.placement_fn for eng in engines]
+        self.policy = self.bridges[0].policy
+        self.decide([b.blank() for b in self.bridges])
+
+    def act(self, metrics, frame: int) -> None:
+        """Observe every cell (its own ``policy_obs`` phase), decide all of
+        them in one ``policy_act_batch`` phase, hand each its row."""
+        seen = [b.observe(eng) for eng, b in zip(self.engines, self.bridges)]
+        cells = len(seen)
+        with phase(metrics, "policy_act_batch", frame=frame, cells=cells):
+            acts = self.decide(seen)
+        if metrics is not None:
+            metrics.counter("policy_act_cells").inc(cells)
+        for bridge, (_, obs_hist), row in zip(self.bridges, seen, acts):
+            bridge.take(frame, obs_hist, row)
+
+    def decide(self, seen) -> np.ndarray:
+        """(E, U) slot actions for E cells' ``(view, obs_hist)``."""
+        return np.asarray(self.policy.act_pooled(*self._stack(seen)))
+
+    @staticmethod
+    def _stack(seen):
+        """One E-env view and (E, H, obs_dim) window from E one-env ones.
+        Node liveness stays None while every cell is healthy; otherwise a
+        healthy cell's row is all up, which leaves its action mask as it
+        is alone."""
+        views = [v for v, _ in seen]
+
+        def rows(name):
+            return np.concatenate([getattr(v, name) for v in views])
+
+        node_up = None
+        if any(v.node_up is not None for v in views):
+            n = views[0].cfg.num_bs
+            node_up = np.concatenate([
+                np.ones((1, n), dtype=bool) if v.node_up is None
+                else v.node_up for v in views])
+        view = _SlotView(views[0].cfg, len(views), rows("chain_state"),
+                         rows("poa"), rows("cur_node"), rows("blocks_done"),
+                         node_up=node_up)
+        hists = [h for _, h in seen]
+        return view, None if hists[0] is None else np.concatenate(hists)
+
+
+def policy_pools(engines: List[ServingEngine]) -> List[PolicyPool]:
+    """Group the cells that can decide together: bridged policies with the
+    same non-None pool key on the same world shape.  Any other cell acts
+    alone in its own ``plan_step``."""
+    groups: Dict[tuple, List[ServingEngine]] = {}
+    for eng in engines:
+        bridge = eng.placement_fn
+        if not isinstance(bridge, ServingPolicy):
+            continue
+        key = bridge.policy.pool_key()
+        if key is not None:
+            groups.setdefault((key, bridge.cfg), []).append(eng)
+    return [PolicyPool(group) for group in groups.values()]
 
 
 # -- deployment helpers --------------------------------------------------------

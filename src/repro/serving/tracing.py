@@ -33,7 +33,9 @@ Two clocks, one per kind of record:
   ``admission``             ``ServingEngine.begin_quantum``: deadlines,
                             failures, admission, degradation, scratch
   ``policy_obs``            the policy bridge's observation and slot view
-  ``policy_act_batch``      the policy's batched ``act_batch`` decision
+  ``policy_act_batch``      the policy's batched decision:
+                            one per pool of cells and quantum in a fleet
+                            (``PolicyPool``), one per cell acting alone
   ``placement``             ``plan_step`` after the decision: placement
                             loop, transmission charging, span hooks
   ``accounting``            ``finish_step`` / ``end_quantum``: delivery,
@@ -52,7 +54,8 @@ Two clocks, one per kind of record:
   shared do-nothing context: no clock read, no annotation, no record.
   The other wall-clock record is ``gdm_compile_ms``/``gdm_compile_events``
   (first call at a new bucket, :meth:`GDMService.instrument
-  <repro.serving.gdm_service.GDMService.instrument>`).
+  <repro.serving.gdm_service.GDMService.instrument>`).  The counter
+  ``policy_act_cells`` adds the cells each ``policy_act_batch`` decided.
 
 Exports:
 

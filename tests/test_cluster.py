@@ -7,19 +7,26 @@
   contract that lets fleet results stand in for N independent engine runs.
 * **Stacked == sequential** — the one-call-per-service fleet execution path
   is bookkeeping-identical to per-cell per-node execution.
+* **Pooled acts** — cells whose policies share a pool key are decided by
+  one batched act per quantum; each cell's row equals its own one-env act
+  bit for bit, and the act's one shape compiles when the cluster is built.
 * **Handover** — in-flight latents migrate between cells with chain
   progress intact, the transfer is charged through the kv_manager ledger,
   and infeasible candidates (no in-flight request / destination slot busy)
   are skipped.
 """
+import functools
+
 import numpy as np
 import pytest
 
-from repro.core.policy import GreedyPoAPolicy, RandomPolicy
+from repro.core.learn_gdm import LearnGDMController
+from repro.core.policy import GreedyPoAPolicy, LearnedPolicy, RandomPolicy
 from repro.serving import (ClusterEngine, HandoverEvent, Request,
                            ServingPolicy, TelemetryLog, TransferLedger,
                            cluster_from_scenario, engine_from_scenario,
                            serve_fleet, serve_trace)
+from repro.sim.env import EdgeSimulator
 from repro.sim.scenarios import get_scenario
 from repro.sim.workloads import fleet_trace
 
@@ -65,6 +72,16 @@ CELLS = 3
 FRAMES = 12
 
 
+def _fresh_agent(seed=0):
+    """An untrained D3QL agent for the smoke world."""
+    return LearnGDMController(EdgeSimulator(get_scenario("smoke")),
+                              variant="learn-gdm", seed=seed).agent
+
+
+# one agent per seed for the whole module, as a fleet shares its agent
+_agent = functools.lru_cache(maxsize=None)(_fresh_agent)
+
+
 def _standalone_runs(cfg, fleet, services, *, policy_factory=None):
     """Reference: each cell's trace served on its own ServingEngine."""
     outs = []
@@ -83,7 +100,11 @@ def _standalone_runs(cfg, fleet, services, *, policy_factory=None):
     None,                                        # engine default placement
     lambda c: GreedyPoAPolicy(),                 # bridged GR per cell
     lambda c: RandomPolicy(seed=c),              # stochastic, per-cell seed
-], ids=["default", "greedy-bridge", "random-bridge"])
+    lambda c: LearnedPolicy(_agent(), "learn-gdm"),   # one agent, one pool
+    lambda c: LearnedPolicy(_agent(c % 2), "mp"),     # two agents, two pools
+    lambda c: GreedyPoAPolicy() if c % 2 else RandomPolicy(seed=c),
+], ids=["default", "greedy-bridge", "random-bridge", "learned-bridge",
+        "learned-two-agents", "pooled-and-alone"])
 def test_cluster_cells_match_standalone_engines(policy_factory):
     cfg = get_scenario("smoke")
     fleet = fleet_trace(cfg, FRAMES, CELLS, workload="stationary", seed=5)
@@ -104,6 +125,101 @@ def test_cluster_cells_match_standalone_engines(policy_factory):
             assert out["steps"][t][c] == ref_log[t], (c, t)
     assert out["completed"] == sum(s[0]["completed"] for s in standalone)
     assert out["submitted"] == sum(s[0]["submitted"] for s in standalone)
+
+
+def _learned_cluster(cfg, services, agents, cells, **kw):
+    return cluster_from_scenario(
+        cfg, cells, services, policy_factory=lambda c: LearnedPolicy(
+            agents[c % len(agents)], "learn-gdm"), **kw)
+
+
+def test_pooled_act_rows_equal_each_cells_own_act():
+    """One act over 3 cells, one of them with a dead node (so the stacked
+    node liveness is exercised), with the bridges' record on: every cell's
+    actions equal its own one-env act on the same view and history."""
+    cfg = get_scenario("smoke")
+    services = _services(cfg)
+    cluster = _learned_cluster(cfg, services, [_agent()], CELLS,
+                               tracing=True)
+    (pool,) = cluster.pools
+    assert pool.engines == cluster.engines
+    up = np.ones(cfg.num_bs, dtype=bool)
+    up[1] = False
+    cluster.engines[2].set_fault_state(up)      # held: serve_fleet sets none
+    seen = {c: [] for c in range(CELLS)}
+    for c, eng in enumerate(cluster.engines):
+        bridge = eng.placement_fn
+        bridge.record = True
+
+        def observe(engine, _inner=bridge.observe, _c=c):
+            seen[_c].append(_inner(engine))
+            return seen[_c][-1]
+
+        bridge.observe = observe
+    serve_fleet(cluster, fleet_trace(cfg, FRAMES, CELLS,
+                                     workload="flash-crowd", seed=5),
+                services, seed=0)
+    placed = 0
+    for c, eng in enumerate(cluster.engines):
+        bridge = eng.placement_fn
+        assert len(bridge.trace) == len(seen[c]) == FRAMES
+        for (frame, hist, actions), (view, obs_hist) in zip(bridge.trace,
+                                                            seen[c]):
+            assert view.num_envs == 1 and np.array_equal(hist, obs_hist)
+            assert (view.node_up is None) == (c != 2)
+            alone = bridge.policy.act_batch(view, obs_hist)[0]
+            assert np.array_equal(actions, alone), (c, frame)
+            if c == 2:
+                assert not (actions == 2).any()  # never onto dead node 1
+            placed += int((actions > 0).sum())
+    assert placed > 0
+    metrics = cluster.metrics
+    assert metrics.histogram("policy_act_batch_ms").count == FRAMES
+    assert metrics.counter("policy_act_cells").value == CELLS * FRAMES
+    assert metrics.histogram("policy_obs_ms").count == CELLS * FRAMES
+
+
+def test_random_policy_cells_act_one_by_one():
+    # a per-instance rng: pooling would change which draws a cell sees
+    cfg = get_scenario("smoke")
+    services = _services(cfg)
+    cluster = cluster_from_scenario(
+        cfg, CELLS, services, policy_factory=lambda c: RandomPolicy(seed=c),
+        tracing=True)
+    assert cluster.pools == []
+    serve_fleet(cluster, fleet_trace(cfg, FRAMES, CELLS, seed=5), services,
+                seed=0)
+    metrics = cluster.metrics
+    assert metrics.histogram("policy_act_batch_ms").count == CELLS * FRAMES
+    assert metrics.counter("policy_act_cells").value == CELLS * FRAMES
+
+
+def test_distinct_agents_form_distinct_pools():
+    cfg = get_scenario("smoke")
+    agents = [_agent(0), _agent(1)]
+    cluster = _learned_cluster(cfg, _services(cfg), agents, 5)
+    pools = {id(p.policy.agent): [e.cell_id for e in p.engines]
+             for p in cluster.pools}
+    assert pools == {id(agents[0]): [0, 2, 4], id(agents[1]): [1, 3]}
+    # the greedy baseline pools by its type; other variants apart
+    mixed = cluster_from_scenario(
+        cfg, 4, _services(cfg), policy_factory=lambda c: (
+            GreedyPoAPolicy(), LearnedPolicy(agents[0], "learn-gdm"),
+            LearnedPolicy(agents[0], "fp"), GreedyPoAPolicy())[c])
+    assert sorted([e.cell_id for e in p.engines] for p in mixed.pools) \
+        == [[0, 3], [1], [2]]
+
+
+def test_pool_compiles_its_act_once_when_the_cluster_is_built():
+    cfg = get_scenario("smoke")
+    agent = _fresh_agent(seed=7)
+    assert agent._qvals_rows._cache_size() == 0
+    services = _services(cfg)
+    cluster = _learned_cluster(cfg, services, [agent], CELLS)
+    assert agent._qvals_rows._cache_size() == 1      # the pool's (3, H, D) act
+    serve_fleet(cluster, fleet_trace(cfg, FRAMES, CELLS, seed=5), services,
+                seed=0)
+    assert agent._qvals_rows._cache_size() == 1      # nothing compiled serving
 
 
 def test_cluster_serves_on_shared_service_instances():

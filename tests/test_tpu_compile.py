@@ -12,6 +12,7 @@ process may load the TPU library at a time, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.models.gdm import LATENT_CHANNELS, init_gdm, make_schedule
+from repro.rl.d3ql import D3QLAgent, D3QLConfig
 from repro.serving.gdm_service import block_runner
 
 CFG = get_config("gdm-dit")                 # full width: 12 x d768, 16x16
@@ -121,3 +123,35 @@ def test_sharded_block_call_compiles_for_v5e_2x2(topo):
                                    NamedSharding(mesh, jax.P("batch")),
                                    mesh=mesh)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _multiply_reduces(text):
+    """The program's fused multiply-reduces (its matmuls on the vector
+    unit), names and metadata dropped: what decides their arithmetic."""
+    out = set()
+    for body in re.findall(r"^%\S+ \(.*?\{\n(.*?)\n\}", text, re.M | re.S):
+        if " reduce(" in body and "multiply(" in body:
+            out.add(re.sub(r"%[\w.\-]+", "%",
+                           re.sub(r", metadata=\{.*", "", body)))
+    return out
+
+
+def test_pooled_policy_act_lowers_like_one_cell_acts(one_chip):
+    """A fleet's pooled act (``D3QLAgent.act_rows``) runs the forward one
+    cell at a time inside one program: the compiler lowers a one-row
+    matmul to an f32 multiply-reduce but an 8-row one to the MXU at
+    default precision, so only the one-row program gives every cell the
+    Q-values, hence the actions, of its own act.  Shapes of the
+    paper-fig3 fleet (15 UEs, 16 nodes)."""
+    agent = D3QLAgent(D3QLConfig(obs_dim=302, num_ues=15, num_actions=17))
+    params = jax.tree_util.tree_map(lambda a: _spec(one_chip, *a.shape),
+                                    agent.params)
+
+    def text(fn, envs):
+        obs = _spec(one_chip, envs, agent.cfg.history, agent.cfg.obs_dim)
+        return fn.lower(params, obs).compile().as_text()
+
+    alone, rows = text(agent._qvals, 1), text(agent._qvals_rows, 8)
+    assert "convolution(" not in rows
+    assert _multiply_reduces(rows) == _multiply_reduces(alone) != set()
+    assert "convolution(" in text(agent._qvals, 8)

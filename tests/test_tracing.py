@@ -310,11 +310,13 @@ def test_latency_summary_matches_numpy():
 def test_policy_bridge_decision_metrics_recorded():
     out, _, _, tracer, _ = _run_fleet(
         _POLICY_FACTORIES["greedy-bridge"](), tracing=True)
-    hist = tracer.metrics.to_json()["histograms"]
-    # one observation phase and one decision per cell and quantum
-    assert hist["policy_act_batch_ms"]["count"] == CELLS * FRAMES
-    assert hist["policy_obs_ms"]["count"] \
-        == hist["policy_act_batch_ms"]["count"]
+    doc = tracer.metrics.to_json()
+    hist = doc["histograms"]
+    # one observation phase per cell and quantum; the cells share one
+    # pool, so one decision per quantum decides all of them
+    assert hist["policy_act_batch_ms"]["count"] == FRAMES
+    assert doc["counters"]["policy_act_cells"] == CELLS * FRAMES
+    assert hist["policy_obs_ms"]["count"] == CELLS * FRAMES
     assert hist["policy_act_batch_ms"]["total"] > 0
 
 
@@ -431,15 +433,21 @@ def test_phase_counts_on_traced_fleet(mode):
     _, _, _, tracer, _ = _run_fleet(_POLICY_FACTORIES["greedy-bridge"](),
                                     tracing=True, **kw)
     hist = tracer.metrics.histograms
+    acted = tracer.metrics.counter("policy_act_cells").value
     quanta = CELLS * FRAMES
     assert hist["admission_ms"].count == quanta
-    assert hist["policy_obs_ms"].count == hist["policy_act_batch_ms"].count
     if mode == "quantum":
-        # one placement pass and one accounting phase per cell and quantum
-        for name in ("placement", "accounting", "policy_act_batch"):
+        # one observation, placement pass and accounting phase per cell
+        # and quantum; one act per quantum for the fleet's one pool
+        for name in ("policy_obs", "placement", "accounting"):
             assert hist[f"{name}_ms"].count == quanta, name
+        assert hist["policy_act_batch_ms"].count == FRAMES
+        assert acted == quanta
     else:
-        # a continuous quantum runs several block steps
+        # a continuous quantum runs several block steps, each cell acting
+        # alone at every one
+        assert hist["policy_obs_ms"].count \
+            == hist["policy_act_batch_ms"].count == acted
         assert hist["placement_ms"].count > quanta
         assert hist["accounting_ms"].count > quanta
     assert hist["fleet_ms"].count > 0
@@ -482,6 +490,7 @@ def test_phases_land_on_the_profiler_host_plane(tmp_path):
                     stats.setdefault(e.name[len(PHASE_PREFIX):], set()).add(
                         tuple(sorted(k for k, _ in e.stats)))
     want = {**{p: ("cell", "frame") for p in ENGINE_PHASES},
+            "policy_act_batch": ("cells", "frame"),      # one act per pool
             "fleet": ("frame",),
             **{p: ("bucket", "rows", "service") for p in SERVICE_PHASES}}
     assert set(stats) == set(PHASES)
