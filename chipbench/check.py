@@ -1,12 +1,21 @@
 """Whether the window's block calls are right: the served outputs against
-the plain reference (``reference/dit.py``).
+the configuration's plain reference, ``reference/<reference>.py``.
 
 After the window, rows are drawn from the calls the window made: the call
 with the most rows first, then calls in an order drawn from the seed,
 every live row of each, until the configuration's ``check.rows``.  Each
-row is the reference's input as the program had it (latent, prompt,
-block index) and the program's output as ``run_batch`` wrote it back to
-the request (new latent and x0 estimate).
+row is the reference's input as the program had it (every array of the
+request's state but the output ``x0``, and the block index) and the
+program's output as ``run_batch`` wrote it back to the request (new
+latent and x0 estimate).
+
+A reference module gives three functions:
+
+* ``service_keys(key, services)``: each service's key for its weights;
+* ``make_params(key, m)``: a service's weights, drawn again from its key;
+* ``block(params, inputs, block_idx, m, *, blocks, dtype, precision)``:
+  one block for each row, ``inputs`` a dict of the rows' stacked arrays;
+  returns (latent after the block, x0 estimate) as numpy.
 
 For each row the error of an output is the distance from the reference's
 output over the distance the reference moved it from the input latent:
@@ -23,8 +32,19 @@ from typing import Dict, List
 
 import numpy as np
 
-from chipbench import seeds
-from chipbench.reference import dit
+from chipbench import parts, seeds
+
+
+def reference(conf: dict):
+    """The configuration's plain reference module."""
+    return parts.load("reference", conf["reference"])
+
+
+def inputs_of(state: dict) -> List[str]:
+    """The keys of a request's state that the reference takes: every
+    array but the output ``x0``."""
+    return [k for k, v in state.items()
+            if k != "x0" and isinstance(v, np.ndarray)]
 
 
 def sample(calls: List[dict], rows: int, seed: int) -> List[tuple]:
@@ -63,22 +83,25 @@ def compare(calls: List[dict], conf: dict, seed: int, weights_key, *,
     by_service: Dict[int, List[tuple]] = {}
     for call, r in picked:
         by_service.setdefault(call["service"], []).append((call, r))
-    keys = dit.service_keys(weights_key, conf["services"])
+    ref = reference(conf)
+    keys = ref.service_keys(weights_key, conf["services"])
     errs = {"latent_err": [], "x0_err": []}
     block = chk["block_rows"]
     for sid in sorted(by_service):
         rows = by_service[sid]
-        params = dit.make_params(keys[sid], m)
-        lat = np.stack([c["states"][r]["latent"] for c, r in rows])
-        prompt = np.stack([c["states"][r]["prompt"] for c, r in rows])
+        params = ref.make_params(keys[sid], m)
+        first_call, first_row = rows[0]
+        inputs = {k: np.stack([c["states"][r][k] for c, r in rows])
+                  for k in inputs_of(first_call["states"][first_row])}
+        lat = inputs["latent"]
         idx = np.asarray([c["idx"][r] for c, r in rows], np.int32)
         out_lat = np.stack([c["out"][r]["latent"] for c, r in rows])
         out_x0 = np.stack([c["out"][r]["x0"] for c, r in rows])
-        ref_lat, ref_x0 = _blocks(params, lat, prompt, idx, m, block,
+        ref_lat, ref_x0 = _blocks(ref, params, inputs, idx, m, block,
                                   m["gdm_blocks"], "float32",
                                   precision or chk["reference_precision"])
         if control:
-            out_lat, out_x0 = _blocks(params, lat, prompt, idx, m, block,
+            out_lat, out_x0 = _blocks(ref, params, inputs, idx, m, block,
                                       m["gdm_blocks"], "bfloat16", "default")
         del params
         errs["latent_err"].append(row_errors(out_lat, ref_lat, lat))
@@ -91,10 +114,11 @@ def compare(calls: List[dict], conf: dict, seed: int, weights_key, *,
     return result
 
 
-def _blocks(params, lat, prompt, idx, m, block, blocks, dtype, precision):
+def _blocks(ref, params, inputs, idx, m, block, blocks, dtype, precision):
     """The reference over the rows in fixed blocks of ``block`` rows (the
-    last padded), so that it compiles once and fits."""
-    n = len(lat)
+    last padded with copies of its first row, in every input), so that it
+    compiles once and fits."""
+    n = len(idx)
     outs_l, outs_x = [], []
     for a in range(0, n, block):
         sl = slice(a, min(a + block, n))
@@ -104,8 +128,9 @@ def _blocks(params, lat, prompt, idx, m, block, blocks, dtype, precision):
         def fill(x):
             return np.concatenate([x[sl], np.repeat(x[sl][:1], pad, axis=0)])
 
-        rl, rx = dit.block(params, fill(lat), fill(prompt), fill(idx), m,
-                           blocks=blocks, dtype=dtype, precision=precision)
+        rl, rx = ref.block(params, {k: fill(v) for k, v in inputs.items()},
+                           fill(idx), m, blocks=blocks, dtype=dtype,
+                           precision=precision)
         outs_l.append(rl[:k])
         outs_x.append(rx[:k])
     return np.concatenate(outs_l), np.concatenate(outs_x)

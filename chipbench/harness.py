@@ -2,9 +2,11 @@
 
 A cell names a configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``) in ``BENCHMARK.json``; nothing here knows any
-cell by name.  The window drives the program's own entry,
-``repro.serving.cluster.serve_fleet``, on a fleet that
-``cluster_from_scenario`` builds, and times it from outside:
+cell by name.  A configuration names its architecture's parts: its plain
+reference (``reference/<reference>.py``, for the check) and its count of
+work (``kernels/<work>.py``, for the readers).  The window drives the
+program's own entry, ``repro.serving.cluster.serve_fleet``, on a fleet
+that ``cluster_from_scenario`` builds, and times it from outside:
 
 * the cluster's ``step`` is wrapped to close the window and to stamp the
   end of every quantum on the host clock.  A request's latency runs from
@@ -23,7 +25,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -38,7 +39,11 @@ import numpy as np
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 
-from chipbench import check, seeds, trace  # noqa: E402
+from chipbench import check, parts, seeds, trace  # noqa: E402
+
+# what a configuration names of its architecture: the check's reference
+# module and the readers' count of work
+PART_KEYS = ("reference", "work")
 
 
 class WindowClosed(Exception):
@@ -72,6 +77,10 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(ROOT / configs[w["config"]]["file"])
+    missing = [k for k in PART_KEYS if k not in config]
+    if missing:
+        raise ValueError(f"configuration {w['config']!r} names no "
+                         f"{' and no '.join(map(repr, missing))}")
     traffic = load_json(HERE / "traffic" / f"{w['traffic']}.json")
 
     def applies(metric):
@@ -137,6 +146,26 @@ def max_rows(trace_fleet, service: int) -> int:
 
 def buckets_upto(svc, rows: int) -> List[int]:
     return sorted({svc._bucket(b) for b in range(1, rows + 1)})
+
+
+def warm_buckets(services: dict, trace_fleet, max_bucket: int,
+                 seed: int) -> Dict[int, List[int]]:
+    """Compile every bucket the traffic can reach: one ``run_batch`` per
+    bucket and service, on full rows of requests the service makes itself
+    (``init_state``), so the warm call has the shapes and types the
+    window's calls have.  Returns the buckets warmed per service."""
+    rng = np.random.default_rng(seeds.stream(seed, "warm-rows"))
+    warmed = {}
+    for sid, svc in services.items():
+        rows = max_rows(trace_fleet, sid)
+        if rows > max_bucket:
+            raise ValueError(f"service {sid} can send {rows} rows a call, "
+                             f"over the traffic's max_bucket {max_bucket}")
+        warmed[sid] = buckets_upto(svc, rows)
+        for b in warmed[sid]:
+            svc.run_batch([svc.init_state(rng) for _ in range(b)],
+                          np.zeros(b, np.int32))
+    return warmed
 
 
 # -- spans -----------------------------------------------------------------------
@@ -294,20 +323,8 @@ def serve(cell: Cell, seed: int, seconds: float, traced: bool, *,
 
     cluster = cluster_from_scenario(cfg, traffic["cells"], services,
                                     policy_factory=policy, tracing=traced)
-    warmed = {}
-    for sid, svc in services.items():
-        rows = max_rows(trace_fleet, sid)
-        if rows > traffic["max_bucket"]:
-            raise ValueError(f"service {sid} can send {rows} rows a call, "
-                             f"over the traffic's max_bucket "
-                             f"{traffic['max_bucket']}")
-        warmed[sid] = buckets_upto(svc, rows)
-        hw2 = mcfg.latent_hw ** 2
-        for b in warmed[sid]:
-            out = svc._call_runner(np.zeros((b, hw2, 4), np.float32),
-                                   np.zeros((b, svc.prompt_len), np.int32),
-                                   np.zeros((b,), np.int32))
-            jax.block_until_ready(out)
+    # before the Recorder wraps run_batch: no warm call is a window call
+    warmed = warm_buckets(services, trace_fleet, traffic["max_bucket"], seed)
     log(f"warm-up: buckets {warmed} ({time.perf_counter() - t:.3f} s)")
 
     rec = Recorder(seconds, traced)
@@ -478,12 +495,14 @@ def counters(metrics) -> Dict[str, float]:
 
 class Context:
     """What a per-layer reader may read: the traced window's profile, the
-    host spans, the calls, the program's counters, shapes and peaks."""
+    host spans, the calls, the program's counters, shapes and peaks, and
+    the configuration's count of work (``work``, ``kernels/<work>.py``)."""
 
     def __init__(self, *, profile, rec, cell, device, before, after,
                  compiles, spb):
         self.profile = profile
         self.model = cell.config["model"]
+        self.work = parts.load("kernels", cell.config["work"])
         self.steps_per_block = spb
         self.calls = rec.calls
         self.before, self.after = before, after
@@ -502,7 +521,7 @@ class Context:
         # the device clock's lag behind the host's, from the block calls
         self.lag = {}
         self.ops = {}
-        names = kernel_module("gdm_block").MODULES
+        names = self.work.MODULES
         launches = [s for s in profile.spans
                     if s.name == trace.SPAN + "run_batch"]
         for chip, ops in profile.ops.items():
@@ -548,18 +567,9 @@ class Context:
                     by.items(), key=lambda kv: -kv[1])[:10]]}
 
 
-def _load_module(kind: str, name: str):
-    path = HERE / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench.{kind}.{name.replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def read_metric(name: str, ctx: Context) -> Optional[float]:
-    return _load_module("metrics", name).read(ctx)
+    return parts.load("metrics", name).read(ctx)
 
 
 def kernel_module(name: str):
-    return _load_module("kernels", name)
+    return parts.load("kernels", name)

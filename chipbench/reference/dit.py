@@ -143,16 +143,17 @@ def _block(params, latent, prompt, block_idx, *, mj, total_steps, dtype):
     return jnp.sqrt(ab_prev) * x0 + jnp.sqrt(1 - ab_prev) * eps, x0
 
 
-def block(params, latent, prompt, block_idx, m: dict, *, blocks: int,
+def block(params, inputs: dict, block_idx, m: dict, *, blocks: int,
           dtype: str = "float32", precision: str = "highest"):
     """One block (one DDIM step) for each row at its own block index:
     returns (latent after the step, x0 estimate), float32 numpy.
+    ``inputs`` holds the rows' stacked ``latent`` and ``prompt``;
     ``precision`` is JAX's default matmul precision for the call."""
     mj = tuple(sorted((k, v) for k, v in m.items()
                       if isinstance(v, (int, float, str))))
     with jax.default_matmul_precision(precision):
-        lat, x0 = _block(params, jnp.asarray(latent, jnp.float32),
-                         jnp.asarray(prompt, jnp.int32),
+        lat, x0 = _block(params, jnp.asarray(inputs["latent"], jnp.float32),
+                         jnp.asarray(inputs["prompt"], jnp.int32),
                          jnp.asarray(block_idx, jnp.int32), mj=mj,
                          total_steps=blocks, dtype=dtype)
     return np.asarray(lat), np.asarray(x0)

@@ -33,6 +33,7 @@ def test_dit_mfu_counts_live_rows_only():
 
     class Ctx:
         model = model("dit-xl2-512")
+        work = gdm_block
         steps_per_block = 1
         window_s = 2.0
         calls = [{"rows": 5, "bucket": 8}, {"rows": 16, "bucket": 16}]
