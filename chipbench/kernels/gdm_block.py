@@ -24,14 +24,24 @@ TIMESTEP_FEATURES = 256
 MODULES = ("jit_run",)
 
 
+def attention(m: dict) -> dict:
+    """The shape of the block call's attention: self-attention over the
+    S latent tokens, one kernel row per request row, one call per
+    layer."""
+    s = m["latent_hw"] ** 2
+    return {"q_len": s, "kv_len": s, "rows_per_request": 1,
+            "calls_per_step": m["num_layers"]}
+
+
 def layer_flops(m: dict) -> int:
     s = m["latent_hw"] ** 2
     d = m["d_model"]
     f = m["d_ff"]
     q = m["num_heads"] * m["head_dim"]
+    a = attention(m)
     proj = 2 * s * d * q * 3 + 2 * s * q * d          # q, k, v, out
     mlp = 2 * 2 * s * d * f
-    attn = 2 * 2 * s * s * q
+    attn = 2 * 2 * a["q_len"] * a["kv_len"] * q * a["rows_per_request"]
     ada = 2 * d * 6 * d
     return proj + mlp + attn + ada
 

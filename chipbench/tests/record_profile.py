@@ -34,7 +34,7 @@ def main() -> int:
         sys.exit("record_profile: needs a TPU")
     conf = harness.load_json(ROOT / "chipbench" / "configs"
                              / "gdm-dit.json")
-    conf["model"].update(small.SMALL_MODEL)
+    conf["model"] = small.model(conf)
     svc = GDMService(jax.random.PRNGKey(0), model_cfg=harness.model_config(
         conf), impl="pallas")
     rng = np.random.default_rng(0)

@@ -1,6 +1,7 @@
 """A cell of the benchmark at a size a CPU test can hold: the real
-traffic files and harness, a DiT with the configuration's structure at
-small widths, and fewer cells and frames."""
+traffic files and harness, the configuration's model with its own
+``small`` overrides (``configs/<name>.json``), and fewer cells and
+frames."""
 from __future__ import annotations
 
 import copy
@@ -8,16 +9,22 @@ import time
 
 from chipbench import harness
 
-SMALL_MODEL = {"num_layers": 2, "d_model": 64, "num_heads": 4,
-               "num_kv_heads": 4, "head_dim": 16, "d_ff": 256,
-               "vocab_size": 128, "latent_hw": 8}
+
+def model(config: dict) -> dict:
+    """The configuration's model with its ``small`` overrides applied; a
+    configuration without them is refused."""
+    if "small" not in config:
+        raise ValueError(f"configuration {config['name']!r} names no "
+                         f"'small'")
+    return {**config["model"], **config["small"]}
 
 
 def cell(name: str = "dit-xl2-512.fleet8-deep", cells: int = 2,
-         frames: int = 3000) -> harness.Cell:
-    c = harness.load_cell(name)
+         frames: int = 3000,
+         bench_path=harness.ROOT / "BENCHMARK.json") -> harness.Cell:
+    c = harness.load_cell(name, bench_path)
     c.config = copy.deepcopy(c.config)
-    c.config["model"].update(SMALL_MODEL)
+    c.config["model"] = model(c.config)
     c.config["check"].update(rows=24, block_rows=8)
     c.traffic = dict(c.traffic, cells=cells, frames=frames,
                      train_episodes=8, train_envs=8)

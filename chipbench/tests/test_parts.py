@@ -14,6 +14,9 @@ count of work (``work``), and the warm-up's requests (the service's own
   work module.
 """
 import json
+import shutil
+import subprocess
+import sys
 import time
 import types
 
@@ -283,7 +286,8 @@ def test_clock_pairing_reads_the_works_programs(monkeypatch, programs,
     monkeypatch.setattr(harness.parts, "load",
                         lambda kind, name: work if kind == "kernels"
                         and name == "stub" else load(kind, name))
-    conf = {"model": small.SMALL_MODEL, "work": "stub"}
+    conf = {"model": small.model(harness.load_json(
+        harness.HERE / "configs" / "gdm-dit.json")), "work": "stub"}
     ctx = harness.Context(
         profile=trace.load(str(DATA)), rec=types.SimpleNamespace(calls=[]),
         cell=types.SimpleNamespace(config=conf), device={"kind": "x"},
@@ -295,8 +299,10 @@ def test_clock_pairing_reads_the_works_programs(monkeypatch, programs,
 
 # -- a configuration without its parts ----------------------------------------
 
-@pytest.mark.parametrize("key", harness.PART_KEYS)
+@pytest.mark.parametrize("key", (*harness.PART_KEYS, "small"))
 def test_a_configuration_must_name_its_parts(tmp_path, key):
+    """``reference`` and ``work`` are refused when the cell loads; the CPU
+    tests' ``small`` size when a small cell is made."""
     bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
     entry = bench["configs"][0]
     conf = harness.load_json(harness.ROOT / entry["file"])
@@ -306,8 +312,150 @@ def test_a_configuration_must_name_its_parts(tmp_path, key):
     (tmp_path / "bench.json").write_text(json.dumps(bench))
     cell = next(w["name"] for w in bench["workloads"]
                 if w["config"] == entry["name"])
+    make = small.cell if key == "small" else harness.load_cell
     with pytest.raises(ValueError, match=f"names no {key!r}"):
-        harness.load_cell(cell, tmp_path / "bench.json")
+        make(cell, bench_path=tmp_path / "bench.json")
+
+
+# -- the CPU tests' size ------------------------------------------------------
+
+# the one small size every configuration ran at before each named its own
+DIT_SMALL = {"num_layers": 2, "d_model": 64, "num_heads": 4,
+             "num_kv_heads": 4, "head_dim": 16, "d_ff": 256,
+             "vocab_size": 128, "latent_hw": 8}
+
+
+@pytest.mark.parametrize("name", ("gdm-dit", "dit-xl2-512"))
+def test_the_dit_configurations_keep_their_small_size(name):
+    conf = harness.load_json(harness.HERE / "configs" / f"{name}.json")
+    assert conf["small"] == DIT_SMALL
+    assert small.model(conf) == dict(conf["model"], **DIT_SMALL)
+    assert list(small.model(conf)) == list(conf["model"])
+
+
+@pytest.mark.parametrize("workload", [
+    w["name"] for w in
+    harness.load_json(harness.ROOT / "BENCHMARK.json")["workloads"]])
+def test_a_small_cell_is_its_configurations_model_with_its_small_keys(
+        workload):
+    c = small.cell(workload)
+    conf = harness.load_cell(workload).config
+    assert c.config["model"] == {**conf["model"], **conf["small"]}
+
+
+# a stub work module: the latent and the context tokens in one sequence,
+# one attention call per layer of either kind
+JOINT_WORK_SRC = '''
+MODULES = ("jit_run",)
+
+
+def attention(m):
+    s = m["latent_hw"] ** 2 + m["context_len"]
+    return {"q_len": s, "kv_len": s, "rows_per_request": 1,
+            "calls_per_step": m["double_layers"] + m["single_layers"]}
+
+
+def flops_per_sample_step(m):
+    s, d = attention(m)["q_len"], m["d_model"]
+    return attention(m)["calls_per_step"] * (24 * s * d * d + 4 * s * s * d)
+'''
+JOINT_WORK = types.ModuleType("joint")
+exec(JOINT_WORK_SRC, JOINT_WORK.__dict__)
+
+
+def joint_bench(tmp_path):
+    """The real ``BENCHMARK.json`` with a stub configuration beside its
+    cells: a joint text-and-image attention and two kinds of layer, its own
+    ``small`` keys among them, naming the DiT reference and the work module
+    ``joint`` (``JOINT_WORK_SRC``)."""
+    model = {"family": "gdm", "num_layers": 57, "double_layers": 19,
+             "single_layers": 38, "d_model": 3072, "num_heads": 24,
+             "num_kv_heads": 24, "head_dim": 128, "rope_axes": [16, 56, 56],
+             "d_ff": 12288, "context_len": 256, "context_dim": 4096,
+             "vocab_size": 49408, "gdm_blocks": 4, "latent_hw": 32}
+    tiny = {"num_layers": 3, "double_layers": 1, "single_layers": 2,
+            "d_model": 64, "num_heads": 4, "num_kv_heads": 4,
+            "head_dim": 16, "rope_axes": [4, 6, 6], "d_ff": 256,
+            "context_len": 8, "context_dim": 32, "vocab_size": 128,
+            "latent_hw": 8}
+    conf = dict(harness.load_json(harness.HERE / "configs" / "gdm-dit.json"),
+                name="joint", work="joint", model=model, small=tiny)
+    (tmp_path / "joint.json").write_text(json.dumps(conf))
+    bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    bench["configs"].append({"name": "joint",
+                             "file": str(tmp_path / "joint.json")})
+    bench["workloads"].append({"name": "joint.fleet8-deep",
+                               "config": "joint", "traffic": "fleet8-deep",
+                               "chips": 1})
+    (tmp_path / "bench.json").write_text(json.dumps(bench))
+    return tmp_path / "bench.json", model, tiny
+
+
+def test_a_joint_attention_configuration_enters_as_files(tmp_path,
+                                                         monkeypatch):
+    bench, model, tiny = joint_bench(tmp_path)
+    full = harness.load_cell("joint.fleet8-deep", bench)
+    assert full.config["model"] == model
+    c = small.cell("joint.fleet8-deep", bench_path=bench)
+    # its own small keys, verbatim: the layer split and the rope axes too
+    assert c.config["model"] == {**model, **tiny}
+    assert c.config["model"]["rope_axes"] == [4, 6, 6]
+    assert c.config["check"]["rows"] == 24 and c.traffic["cells"] == 2
+
+    if not DATA.exists():
+        pytest.skip("no recorded profile")
+    load = parts.load
+    monkeypatch.setattr(harness.parts, "load",
+                        lambda kind, name: JOINT_WORK if kind == "kernels"
+                        and name == "joint" else load(kind, name))
+    buckets = [1, 2, 4, 8, 16, 24]
+    ctx = harness.Context(
+        profile=trace.load(str(DATA)),
+        rec=types.SimpleNamespace(calls=[{"rows": b, "bucket": b}
+                                         for b in buckets]),
+        cell=types.SimpleNamespace(config=full.config),
+        device={"kind": "TPU v5 lite"}, before={}, after={}, compiles=0,
+        spb=1)
+    measured, n = trace.kernel_s(ctx.chip_ops(), ("flash_attention",),
+                                 ctx.lo, ctx.hi)
+    # 1,024 + 256 tokens, 57 calls a step, 24x128 heads: compute-bound
+    tokens = 1024 + 256
+    want = 100.0 * sum(57 * 4 * b * 24 * tokens * tokens * 128 / 197e12
+                       for b in buckets) / measured
+    got = harness.read_metric("flash_attention_roofline", ctx)
+    assert n > 0 and got == pytest.approx(want, rel=1e-12)
+
+
+# the modules that read the benchmark's entries without serving a cell at
+# full size; ``test_control`` and ``test_tpu_compile`` serve every cell,
+# which the program can do only for an architecture it implements
+STUB_MODULES = ("test_parts.py", "test_kernels.py", "test_phases.py",
+                "test_policy_cells.py")
+
+
+def test_the_joint_stub_beside_the_real_cells_passes_the_tests_unedited(
+        tmp_path):
+    """A copy of ``chipbench/`` whose ``BENCHMARK.json`` holds the joint
+    stub beside the real cells, with the stub's work module as a file:
+    the tests that read the benchmark pass there as they stand, the stub
+    among their cases."""
+    root = tmp_path / "checkout"
+    shutil.copytree(harness.HERE, root / "chipbench",
+                    ignore=shutil.ignore_patterns(".*", "__pycache__"))
+    (root / "src").symlink_to(harness.ROOT / "src")
+    bench, _, _ = joint_bench(tmp_path)
+    shutil.copy(bench, root / "BENCHMARK.json")
+    (root / "chipbench" / "kernels" / "joint.py").write_text(JOINT_WORK_SRC)
+    this = ("chipbench/tests/test_parts.py::test_the_joint_stub_beside_the_"
+            "real_cells_passes_the_tests_unedited")
+    run = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         *(f"chipbench/tests/{m}" for m in STUB_MODULES),
+         "--deselect", this],
+        cwd=root, capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stdout[-4000:] + run.stderr[-2000:]
+    assert ("test_a_small_cell_is_its_configurations_model_with_its_small_"
+            "keys[joint.fleet8-deep] PASSED") in run.stdout
 
 
 def test_every_configuration_names_parts_that_exist():

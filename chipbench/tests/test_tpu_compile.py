@@ -2,8 +2,9 @@
 the largest bucket its cells warm, and fits the chip with three services'
 weights beside it.  No chip is needed; the test skips where the topology
 cannot be described.  The configurations and their buckets come from
-``BENCHMARK.json``, and the call's arguments from the service's own
-request state, so a new configuration is covered by its entries alone.
+``BENCHMARK.json``; the program is the one the service builds, with its
+own schedule, and its arguments come from the service's own request
+state, so a new configuration is covered by its entries alone.
 
     python -m pytest -q chipbench/tests/test_tpu_compile.py -s
 """
@@ -48,8 +49,9 @@ def one_chip():
 
 @pytest.fixture
 def shape_only_service(monkeypatch):
-    """A service as ``make_gdm_services`` builds it, with its weights as
-    shapes and Ω left unmeasured: nothing is computed at full width."""
+    """A service as ``make_gdm_services`` builds it on Pallas kernels,
+    with its weights as shapes and Ω left unmeasured: nothing is computed
+    at full width."""
     import jax
     from repro.serving import gdm_service
     init = gdm_service.init_gdm
@@ -62,7 +64,7 @@ def shape_only_service(monkeypatch):
     def build(mcfg, steps_per_block):
         services, _ = gdm_service.make_gdm_services(
             1, jax.random.PRNGKey(0), num_blocks=mcfg.gdm_blocks,
-            steps_per_block=steps_per_block, model_cfg=mcfg)
+            steps_per_block=steps_per_block, model_cfg=mcfg, impl="pallas")
         return services[0]
 
     return build
@@ -74,30 +76,24 @@ def shape_only_service(monkeypatch):
 def test_block_call_compiles_and_fits(one_chip, shape_only_service, entry,
                                       bucket):
     import jax
-    from repro.models.gdm import make_schedule
-    from repro.serving.gdm_service import block_runner
 
     conf = harness.load_json(harness.ROOT / entry["file"])
-    mcfg = harness.model_config(conf)
-    blocks = mcfg.gdm_blocks
-    spb = conf["steps_per_block"]
-    svc = shape_only_service(mcfg, spb)
-    runner = block_runner(mcfg, make_schedule(blocks * spb),
-                          steps_per_block=spb, total_steps=blocks * spb,
-                          impl="pallas")
+    svc = shape_only_service(harness.model_config(conf),
+                             conf["steps_per_block"])
 
     def shaped(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
-    # the runner takes the weights, the request's arrays stacked to the
-    # bucket in the order its state holds them, and the block indices
+    # the service's own block program takes the weights, the request's
+    # arrays stacked to the bucket in the order its state holds them, and
+    # the block indices
     state = svc.init_state(np.random.default_rng(0))
     rows = [shaped(jax.ShapeDtypeStruct((bucket, *state[k].shape),
                                         state[k].dtype))
             for k in check.inputs_of(state)]
     args = (jax.tree_util.tree_map(shaped, svc.params), *rows,
             shaped(jax.ShapeDtypeStruct((bucket,), np.int32)))
-    compiled = runner.lower(*args).compile()
+    compiled = svc._runner.lower(*args).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     weights = mem.argument_size_in_bytes
